@@ -30,6 +30,7 @@ from __future__ import annotations
 import json
 import os
 import time
+import traceback
 
 import jax
 import jax.numpy as jnp
@@ -871,7 +872,10 @@ def main(argv=None) -> None:
     if unknown:
         raise SystemExit(f"unknown benchmark(s) {unknown}; "
                          f"choose from {list(BENCHES)}")
+    from repro.launch import compile_cache
+    compile_cache.enable()
     print("name,us_per_call,derived")
+    failed = []
     for name in names:
         fn = BENCHES[name]
         t0 = time.time()
@@ -879,10 +883,14 @@ def main(argv=None) -> None:
         try:
             fn()
         except Exception as e:  # pragma: no cover
+            traceback.print_exc()
             row(f"{fn.__name__}/ERROR", 0.0, repr(e)[:120])
+            failed.append(name)
         _write_json(name, ROWS[start:])
         print(f"# {fn.__name__} done in {time.time()-t0:.1f}s",
               flush=True)
+    if failed:
+        raise SystemExit(f"benchmark(s) failed: {failed}")
 
 
 if __name__ == "__main__":

@@ -1,15 +1,1 @@
-"""repro: hybrid ZO/FO split federated learning reproduction.
-
-Sharding-invariant PRNG is load-bearing for this repo: seed-replay
-reconstruction regenerates client perturbation directions on the server,
-possibly under a different mesh partitioning than the client used.  With
-the legacy (non-partitionable) threefry lowering, GSPMD may rewrite the
-generation so the *values* depend on the sharding of the consumer — a
-direction sampled inside a mesh-partitioned step then disagrees with its
-single-device replay.  ``jax_threefry_partitionable`` restores the
-counter-based semantics: identical bits for identical keys, regardless of
-mesh or partitioning.
-"""
-import jax
-
-jax.config.update("jax_threefry_partitionable", True)
+"""repro: hybrid ZO/FO split federated learning reproduction."""

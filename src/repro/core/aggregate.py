@@ -9,7 +9,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import zo as Z
-from repro.distributed.sharding import shard_map_compat
 from repro.kernels import ops as O
 
 
@@ -161,8 +160,8 @@ def _replay_engine(global_params, tokens, scales, make_direction,
         def body(tl, sl):
             acc = scan_into(zeros_acc(), tl, sl)
             return jax.tree.map(lambda a: jax.lax.psum(a, shard), acc)
-        return shard_map_compat(body, mesh, in_specs=(tok_spec, P(shard)),
-                                out_specs=P())(toks, scs)
+        return jax.shard_map(body, mesh=mesh, in_specs=(tok_spec, P(shard)),
+                             out_specs=P(), check_vma=False)(toks, scs)
 
     if chunk is None:
         m_pad = -(-m // n_sh) * n_sh

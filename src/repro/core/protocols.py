@@ -54,7 +54,7 @@ class ModelAPI:
     seed_pred: Callable | None = None
 
 
-def _forward_impl_of(cfg) -> str | None:
+def forward_impl_of(cfg) -> str | None:
     """Resolve a model config's forward_impl knob to a matmul backend
     (None = the classic XLA/threefry path, no dual-probe kernels)."""
     fi = getattr(cfg, "forward_impl", "xla")
@@ -99,7 +99,7 @@ def lm_api(cfg: ModelConfig, rules: AxisRules) -> ModelAPI:
         return T.lm_loss(logits, batch["labels"], cfg.vocab)
 
     client_dual_loss = None
-    impl = _forward_impl_of(cfg)
+    impl = forward_impl_of(cfg)
     if impl is not None:
         def client_dual_loss(cp, batch, seeds, mu):
             pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl=impl)
@@ -139,7 +139,7 @@ def cnn_api(cfg: CNN.CNNConfig) -> ModelAPI:
         return CNN.xent(CNN.server_logits(sp, s, cfg), batch["labels"])
 
     client_dual_loss = None
-    impl = _forward_impl_of(cfg)
+    impl = forward_impl_of(cfg)
     if impl is not None:
         def client_dual_loss(cp, batch, seeds, mu):
             pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl=impl)

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import checkpoint as CKPT
+from repro.distributed.sharding import auto_mesh
 
 
 @dataclasses.dataclass
@@ -45,7 +46,7 @@ def remesh(model_parallel: int = 1):
     n = jax.device_count()
     mp = model_parallel if model_parallel > 0 and n % model_parallel == 0 \
         else 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return auto_mesh((n // mp, mp), ("data", "model"))
 
 
 def backoff_s(attempt: int, base: float = 0.05, cap: float = 1.0) -> float:

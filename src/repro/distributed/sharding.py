@@ -16,7 +16,7 @@ from typing import Any, Mapping, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # ---------------------------------------------------------------------------
 # Default logical -> mesh axis rules.
@@ -140,25 +140,13 @@ class AxisRules:
         return NamedSharding(self.mesh, self.spec_for(shape, logical))
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs,
-                     check_rep: bool = False):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` (replication-check kwarg named
-    ``check_vma``); older versions only have
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check_rep)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_rep)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
+def auto_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    """``jax.make_mesh`` with Auto axes.  The model's :func:`constrain`
+    calls are sharding hints, which ``make_mesh``'s default Explicit
+    axes reject."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def constrain(x: jax.Array, rules: AxisRules, logical: Sequence[str | None]) -> jax.Array:

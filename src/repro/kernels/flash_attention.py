@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.zo_matmul import uniform_noise
+from repro.kernels.zo_matmul import pad_to, tile, uniform_noise
 
 NEG_INF = -2.0e38
 
@@ -91,18 +91,12 @@ def flash_attention(q, k, v, *, causal=True, window=0, cap=0.0,
     Skv, Kv = k.shape[1], k.shape[2]
     G = H // Kv
     scale = float(scale) if scale is not None else D ** -0.5
-    bq = min(bq, Sq)
-    bk = min(bk, Skv)
-    assert Sq % bq == 0, (Sq, bq)
-    nk = -(-Skv // bk)
-    Skv_p = nk * bk
-    kp = jnp.pad(k, ((0, 0), (0, Skv_p - Skv), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, Skv_p - Skv), (0, 0), (0, 0)))
-    nq = Sq // bq
-    # (BH, S, D) layouts
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
-    kf = kp.transpose(0, 2, 1, 3).reshape(B * Kv, Skv_p, D)
-    vf = vp.transpose(0, 2, 1, 3).reshape(B * Kv, Skv_p, D)
+    (bq, Sq_p), (bk, Skv_p) = _seq_tiles(Sq, Skv, bq, bk, interpret)
+    nq, nk = Sq_p // bq, Skv_p // bk
+    # (BH, S, D) layouts; padded kv columns are masked, padded q rows
+    # are sliced off
+    qf = _flat(q, Sq_p)
+    kf, vf = _flat(k, Skv_p), _flat(v, Skv_p)
 
     def kv_index(bh, qi, ki):
         b = bh // H
@@ -121,7 +115,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, cap=0.0,
             pl.BlockSpec((1, bk, D), kv_index),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
@@ -129,7 +123,27 @@ def flash_attention(q, k, v, *, causal=True, window=0, cap=0.0,
         ],
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    return _unflat(out, B, Sq)
+
+
+def _seq_tiles(Sq, Skv, bq, bk, interpret):
+    """(block, padded extent) for the q and kv sequence axes: rows align
+    to 8 on the compiled path (head_dim is always a whole block)."""
+    align = 1 if interpret else 8
+    return tile(Sq, bq, align), tile(Skv, bk, align)
+
+
+def _flat(t, s_pad):
+    """(B, S, H, D) -> (B*H, s_pad, D), zero-padded along the sequence."""
+    B, S, H, D = t.shape
+    t = pad_to(t, (B, s_pad, H, D))
+    return t.transpose(0, 2, 1, 3).reshape(B * H, s_pad, D)
+
+
+def _unflat(o, B, S):
+    """(B*H, S_pad, D) -> (B, S, H, D), dropping padded rows."""
+    BH, s_pad, D = o.shape
+    return o.reshape(B, BH // B, s_pad, D)[:, :, :S].transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -185,8 +199,8 @@ def _zo_dual_fa_kernel(seed_ref, mu_ref, off_ref, qa_ref, qb_ref, k_ref,
         # canonical (n_heads*Sq, Skv) field; batch-independent, so the
         # direction is one field per layer regardless of batch size
         h = bh % n_heads
-        noise = uniform_noise(seed_ref[0], (bq, bk),
-                              row_offset=off_ref[0] + h * seq_q + qi * bq,
+        noise = uniform_noise(seed_ref[0, 0], (bq, bk),
+                              row_offset=off_ref[0, 0] + h * seq_q + qi * bq,
                               col_offset=ki * bk)
 
     def stream(q_ref2, kk_ref, vv_ref, m_ref, l_ref, acc_ref, o_ref,
@@ -200,7 +214,7 @@ def _zo_dual_fa_kernel(seed_ref, mu_ref, off_ref, qa_ref, qb_ref, k_ref,
         if pert:
             # post-softcap, pre-mask: an additive fixed-coordinate
             # direction on the score field (masked positions never see it)
-            s = s + mu_ref[mu_ix] * noise
+            s = s + mu_ref[0, mu_ix] * noise
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -256,19 +270,8 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
     Skv, Kv = k.shape[1], k.shape[2]
     G = H // Kv
     scale = float(scale) if scale is not None else D ** -0.5
-    bq = min(bq, Sq)
-    bk = min(bk, Skv)
-    assert Sq % bq == 0, (Sq, bq)
-    nk = -(-Skv // bk)
-    Skv_p = nk * bk
-    nq = Sq // bq
-
-    def flat_q(q):
-        return q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
-
-    def flat_kv(t):
-        tp = jnp.pad(t, ((0, 0), (0, Skv_p - Skv), (0, 0), (0, 0)))
-        return tp.transpose(0, 2, 1, 3).reshape(B * Kv, Skv_p, D)
+    (bq, Sq_p), (bk, Skv_p) = _seq_tiles(Sq, Skv, bq, bk, interpret)
+    nq, nk = Sq_p // bq, Skv_p // bk
 
     def kv_index(bh, qi, ki):
         b = bh // H
@@ -276,18 +279,18 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
         return b * Kv + h // G, ki, 0
 
     shared = kb is None
-    seed_arr = jnp.asarray([seed], jnp.int32)
-    mu_arr = jnp.asarray([mu_a, mu_b], jnp.float32)
-    off_arr = jnp.asarray([row_offset], jnp.int32)
+    seed_arr = jnp.asarray([[seed]], jnp.int32)
+    mu_arr = jnp.asarray([[mu_a, mu_b]], jnp.float32)
+    off_arr = jnp.asarray([[row_offset]], jnp.int32)
     q_spec = pl.BlockSpec((1, bq, D), lambda bh, qi, ki: (bh, qi, 0))
     kv_spec = pl.BlockSpec((1, bk, D), kv_index)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [smem, smem, smem, q_spec, q_spec, kv_spec, kv_spec]
-    args = [seed_arr, mu_arr, off_arr, flat_q(qa), flat_q(qb),
-            flat_kv(k), flat_kv(v)]
+    args = [seed_arr, mu_arr, off_arr, _flat(qa, Sq_p), _flat(qb, Sq_p),
+            _flat(k, Skv_p), _flat(v, Skv_p)]
     if not shared:
         in_specs += [kv_spec, kv_spec]
-        args += [flat_kv(kb), flat_kv(vb)]
+        args += [_flat(kb, Skv_p), _flat(vb, Skv_p)]
     kernel = functools.partial(
         _zo_dual_fa_kernel, nk=nk, bq=bq, bk=bk, causal=causal,
         window=window, cap=float(cap), scale=scale, seq_kv=Skv,
@@ -298,8 +301,8 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
         grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=[q_spec, q_spec],
-        out_shape=[jax.ShapeDtypeStruct((B * H, Sq, D), qa.dtype),
-                   jax.ShapeDtypeStruct((B * H, Sq, D), qb.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((B * H, Sq_p, D), qa.dtype),
+                   jax.ShapeDtypeStruct((B * H, Sq_p, D), qb.dtype)],
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
@@ -310,8 +313,4 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
         ],
         interpret=interpret,
     )(*args)
-
-    def unflat(o):
-        return o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
-
-    return unflat(oa), unflat(ob)
+    return _unflat(oa, B, Sq), _unflat(ob, B, Sq)

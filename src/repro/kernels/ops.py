@@ -49,15 +49,6 @@ def default_forward_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def _divisor_block(dim: int, pref: int) -> int:
-    """Largest block <= pref that tiles dim exactly (interpret-friendly;
-    on TPU callers should pass aligned shapes/blocks explicitly)."""
-    b = min(pref, dim)
-    while dim % b:
-        b -= 1
-    return b
-
-
 def _resolve(impl):
     if impl is None:
         return "pallas" if jax.default_backend() == "tpu" else "interpret"
@@ -76,9 +67,6 @@ def zo_matmul(x, w, seed, mu, *, row_offset=0, impl=None, **kw):
         wf = w.astype(jnp.float32) + jnp.asarray(mu, jnp.float32) * u
         return (x.astype(jnp.float32) @ wf).astype(x.dtype)
     kw.setdefault("interpret", impl == "interpret" or _interpret())
-    kw.setdefault("bm", _divisor_block(x.shape[0], 128))
-    kw.setdefault("bn", _divisor_block(w.shape[1], 128))
-    kw.setdefault("bk", _divisor_block(w.shape[0], 128))
     return ZM.zo_matmul(x, w, seed, mu, row_offset=row_offset, **kw)
 
 
@@ -97,9 +85,6 @@ def zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b, *, row_offset=0, impl=None,
         yb = (xb.astype(jnp.float32) @ wb).astype(xb.dtype)
         return ya, yb
     kw.setdefault("interpret", impl == "interpret" or _interpret())
-    kw.setdefault("bm", _divisor_block(xa.shape[0], 128))
-    kw.setdefault("bn", _divisor_block(w.shape[1], 128))
-    kw.setdefault("bk", _divisor_block(w.shape[0], 128))
     return ZM.zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b,
                              row_offset=row_offset, perturb_a=perturb_a,
                              perturb_b=perturb_b, **kw)
@@ -123,8 +108,6 @@ def zo_dual_forward_split(x, w, seed, mu, **kw):
 
 def zo_noise(w, seed, **kw):
     kw.setdefault("interpret", _interpret())
-    kw.setdefault("bn", _divisor_block(w.shape[1], 128))
-    kw.setdefault("bk", _divisor_block(w.shape[0], 128))
     return ZM.zo_noise(w, seed, **kw)
 
 

@@ -46,6 +46,32 @@ from jax.experimental.pallas import tpu as pltpu
 SQRT3 = 1.7320508075688772
 
 
+def tile(dim: int, pref: int, align: int) -> tuple[int, int]:
+    """(block, padded extent) for one grid axis.
+
+    The whole axis when it fits in one block; else the largest multiple
+    of ``align`` in [pref/2, pref] that divides it; else ``pref``
+    (rounded down to ``align``) with the axis padded up to whole blocks.
+    Compiled kernels pass Mosaic's tiling (8 rows, 128 lanes) as
+    ``align``, so no block they see is unaligned; interpret mode passes
+    1 and keeps the caller's exact divisor blocks.
+    """
+    if dim <= pref:
+        return dim, dim
+    top = max(align, pref - pref % align)
+    for b in range(top, top // 2 - 1, -align):
+        if dim % b == 0:
+            return b, dim
+    return top, -(-dim // top) * top
+
+
+def pad_to(x, shape):
+    """Zero-pad ``x`` at the end of every axis up to ``shape``."""
+    if tuple(x.shape) == tuple(shape):
+        return x
+    return jnp.pad(x, [(0, t - s) for s, t in zip(x.shape, shape)])
+
+
 def _mix_bits(seed_u32, r_u32, c_u32):
     """murmur3-style finalizer over (seed, global row, global col)."""
     x = (r_u32 * jnp.uint32(0x9E3779B9)) ^ (c_u32 * jnp.uint32(0x85EBCA6B))
@@ -59,7 +85,11 @@ def _mix_bits(seed_u32, r_u32, c_u32):
 
 
 def _bits_to_uniform(bits):
-    u01 = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
+    """Top 24 hash bits -> uniform(-sqrt3, sqrt3).  The bits go through
+    int32 (Mosaic lowers no uint32 -> f32 cast); every step before the
+    final scale is exact in f32, so XLA, interpret mode and the compiled
+    kernel round the same single product."""
+    u01 = (bits >> 8).astype(jnp.int32).astype(jnp.float32) * (2.0 ** -24)
     return (u01 * 2.0 - 1.0) * SQRT3
 
 
@@ -107,10 +137,10 @@ def _zo_matmul_kernel(seed_ref, mu_ref, off_ref, x_ref, w_ref, o_ref,
 
     w = w_ref[...].astype(jnp.float32)
     if gen_noise:
-        u = uniform_noise(seed_ref[0], (bk, bn),
-                          row_offset=off_ref[0] + ki * bk,
+        u = uniform_noise(seed_ref[0, 0], (bk, bn),
+                          row_offset=off_ref[0, 0] + ki * bk,
                           col_offset=ni * bn)
-        w = w + mu_ref[0] * u
+        w = w + mu_ref[0, 0] * u
     acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
                             preferred_element_type=jnp.float32)
 
@@ -129,20 +159,22 @@ def zo_matmul(x, w, seed, mu, *, row_offset=0, bm: int = 128, bn: int = 128,
     ``interpret=False``.  ``perturb=False`` degenerates to a plain
     blocked matmul (the clean forward of the two-point estimator).
     ``row_offset`` shifts the global noise rows (stacked scan leaves).
+    ``bm``/``bn``/``bk`` are preferred block sizes (see :func:`tile`);
+    padded rows/columns are zeros and are sliced off the result.
     """
     M, K = x.shape
     K2, N = w.shape
     assert K == K2
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (
-        "pad inputs to tile multiples", (M, K, N), (bm, bk, bn))
-    nm, nn, nk = M // bm, N // bn, K // bk
-    seed_arr = jnp.asarray([seed], jnp.int32)
-    mu_arr = jnp.asarray([mu], jnp.float32)
-    off_arr = jnp.asarray([row_offset], jnp.int32)
+    (bm, Mp), (bn, Np), (bk, Kp) = _matmul_tiles(M, N, K, bm, bn, bk,
+                                                 interpret)
+    x, w = pad_to(x, (Mp, Kp)), pad_to(w, (Kp, Np))
+    nm, nn, nk = Mp // bm, Np // bn, Kp // bk
+    seed_arr = jnp.asarray([[seed]], jnp.int32)
+    mu_arr = jnp.asarray([[mu]], jnp.float32)
+    off_arr = jnp.asarray([[row_offset]], jnp.int32)
     kernel = functools.partial(_zo_matmul_kernel, nk=nk, bk=bk, bn=bn,
                                gen_noise=perturb)
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
         grid=(nm, nn, nk),
         in_specs=[
@@ -153,10 +185,18 @@ def zo_matmul(x, w, seed, mu, *, row_offset=0, bm: int = 128, bn: int = 128,
             pl.BlockSpec((bk, bn), lambda mi, ni, ki: (ki, ni)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(seed_arr, mu_arr, off_arr, x, w)
+    return y[:M, :N]
+
+
+def _matmul_tiles(M, N, K, bm, bn, bk, interpret):
+    """Per-axis (block, padded extent): rows align to 8 and lanes to 128
+    on the compiled path."""
+    row, lane = (1, 1) if interpret else (8, 128)
+    return tile(M, bm, row), tile(N, bn, lane), tile(K, bk, lane)
 
 
 # ---------------------------------------------------------------------------
@@ -176,11 +216,11 @@ def _zo_dual_kernel(seed_ref, mu_ref, off_ref, xa_ref, xb_ref, w_ref,
 
     w = w_ref[...].astype(jnp.float32)
     if perturb_a or perturb_b:
-        u = uniform_noise(seed_ref[0], (bk, bn),
-                          row_offset=off_ref[0] + ki * bk,
+        u = uniform_noise(seed_ref[0, 0], (bk, bn),
+                          row_offset=off_ref[0, 0] + ki * bk,
                           col_offset=ni * bn)
-    wa = w + mu_ref[0] * u if perturb_a else w
-    wb = w + mu_ref[1] * u if perturb_b else w
+    wa = w + mu_ref[0, 0] * u if perturb_a else w
+    wb = w + mu_ref[0, 1] * u if perturb_b else w
     acca_ref[...] += jnp.dot(xa_ref[...].astype(jnp.float32), wa,
                              preferred_element_type=jnp.float32)
     accb_ref[...] += jnp.dot(xb_ref[...].astype(jnp.float32), wb,
@@ -215,16 +255,17 @@ def zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b, *, row_offset=0,
     assert xb.shape == xa.shape, (xa.shape, xb.shape)
     K2, N = w.shape
     assert K == K2
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (
-        "pad inputs to tile multiples", (M, K, N), (bm, bk, bn))
-    nm, nn, nk = M // bm, N // bn, K // bk
-    seed_arr = jnp.asarray([seed], jnp.int32)
-    mu_arr = jnp.asarray([mu_a, mu_b], jnp.float32)
-    off_arr = jnp.asarray([row_offset], jnp.int32)
+    (bm, Mp), (bn, Np), (bk, Kp) = _matmul_tiles(M, N, K, bm, bn, bk,
+                                                 interpret)
+    xa, xb = pad_to(xa, (Mp, Kp)), pad_to(xb, (Mp, Kp))
+    w = pad_to(w, (Kp, Np))
+    nm, nn, nk = Mp // bm, Np // bn, Kp // bk
+    seed_arr = jnp.asarray([[seed]], jnp.int32)
+    mu_arr = jnp.asarray([[mu_a, mu_b]], jnp.float32)
+    off_arr = jnp.asarray([[row_offset]], jnp.int32)
     kernel = functools.partial(_zo_dual_kernel, nk=nk, bk=bk, bn=bn,
                                perturb_a=perturb_a, perturb_b=perturb_b)
-    return pl.pallas_call(
+    ya, yb = pl.pallas_call(
         kernel,
         grid=(nm, nn, nk),
         in_specs=[
@@ -239,12 +280,13 @@ def zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b, *, row_offset=0,
             pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
             pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((M, N), xa.dtype),
-                   jax.ShapeDtypeStruct((M, N), xb.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((Mp, Np), xa.dtype),
+                   jax.ShapeDtypeStruct((Mp, Np), xb.dtype)],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(seed_arr, mu_arr, off_arr, xa, xb, w)
+    return ya[:M, :N], yb[:M, :N]
 
 
 # ---------------------------------------------------------------------------
@@ -254,7 +296,7 @@ def zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b, *, row_offset=0,
 def _noise_kernel(seed_ref, u_ref, *, bk: int, bn: int):
     ki = pl.program_id(1)
     ni = pl.program_id(0)
-    u_ref[...] = uniform_noise(seed_ref[0], (bk, bn),
+    u_ref[...] = uniform_noise(seed_ref[0, 0], (bk, bn),
                                row_offset=ki * bk,
                                col_offset=ni * bn).astype(u_ref.dtype)
 
@@ -267,15 +309,15 @@ def zo_noise(w_shape_like, seed, *, bn: int = 128, bk: int = 128,
     stream is addressed by global coordinates, the result is independent
     of ``bn``/``bk`` and equals ``uniform_noise(seed, w.shape)``."""
     K, N = w_shape_like.shape
-    bn, bk = min(bn, N), min(bk, K)
-    assert N % bn == 0 and K % bk == 0
-    nn, nk = N // bn, K // bk
-    seed_arr = jnp.asarray([seed], jnp.int32)
-    return pl.pallas_call(
+    _, (bn, Np), (bk, Kp) = _matmul_tiles(1, N, K, 1, bn, bk, interpret)
+    nn, nk = Np // bn, Kp // bk
+    seed_arr = jnp.asarray([[seed]], jnp.int32)
+    u = pl.pallas_call(
         functools.partial(_noise_kernel, bk=bk, bn=bn),
         grid=(nn, nk),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((bk, bn), lambda ni, ki: (ki, ni)),
-        out_shape=jax.ShapeDtypeStruct((K, N), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Kp, Np), jnp.float32),
         interpret=interpret,
     )(seed_arr)
+    return u[:K, :N]

@@ -25,6 +25,7 @@ from repro.models import transformer as T     # noqa: E402
 from repro.optim.optimizers import make_optimizer  # noqa: E402
 
 FSDP_THRESHOLD = 3e9  # params; above this, shard storage over data axes
+TARGET_KIND = "TPU v5 lite"  # the chip the production pod meshes model
 
 
 # ---------------------------------------------------------------------------
@@ -287,7 +288,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     compiled = lowered.compile()
     t_compile = time.time() - t0
     n_chips = mesh.size
-    terms = RL.roofline_terms(compiled)
+    terms = RL.roofline_terms(compiled, device_kind=TARGET_KIND)
     mem = RL.memory_summary(compiled)
     mf_global = RL.model_flops(cfg, tokens, counts["active_nonembed"])
     if shape.kind == "train":

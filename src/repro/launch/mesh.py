@@ -7,20 +7,21 @@ Multi-pod: 2x16x16 = 512 chips ("pod","data","model").
 from __future__ import annotations
 
 import jax
-import numpy as np
+
+from repro.distributed.sharding import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(model_parallel: int = 1):
     """Elastic: build a mesh from whatever devices are visible."""
     n = jax.device_count()
     mp = model_parallel if n % model_parallel == 0 else 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return auto_mesh((n // mp, mp), ("data", "model"))
 
 
 def make_replay_mesh(n_devices: int | None = None):
@@ -31,5 +32,4 @@ def make_replay_mesh(n_devices: int | None = None):
     devs = jax.devices()
     if n_devices is not None:
         devs = devs[:n_devices]
-    from jax.sharding import Mesh
-    return Mesh(np.asarray(devs), ("clients",))
+    return auto_mesh((len(devs),), ("clients",), devices=devs)

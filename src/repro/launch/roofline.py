@@ -1,10 +1,11 @@
 """Roofline derivation from a compiled dry-run artifact.
 
-Three terms (seconds, per chip):
+Three terms (seconds, per chip), against the peaks of the chip the
+program targets (:data:`PEAKS`, keyed by ``jax.Device.device_kind``):
 
-    compute    = HLO_FLOPs            / peak_FLOP/s        (197 TF/s bf16)
-    memory     = HLO_bytes_accessed   / HBM_bw             (819 GB/s)
-    collective = collective_bytes     / link_bw            (50 GB/s/link)
+    compute    = HLO_FLOPs            / peak_FLOP/s
+    memory     = HLO_bytes_accessed   / HBM_bw
+    collective = collective_bytes     / link_bw
 
 ``cost_analysis()`` of an SPMD-partitioned executable reports the
 *per-device* program, so no further division by chip count is applied.
@@ -16,9 +17,29 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12          # TPU v5e bf16
-HBM_BW = 819e9
-ICI_BW = 50e9                # per chip per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float             # bf16 FLOP/s
+    hbm_bw: float            # HBM bytes/s
+    ici_bw: float            # interconnect bytes/s per link
+
+
+# Published per-chip peaks.  TPU v5e: Google Cloud documentation, "TPU
+# v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of interconnect
+# (200 GB/s over 4 links).
+PEAKS = {"TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9)}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; a kind without published peaks is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -93,14 +114,17 @@ def collective_bytes(hlo_text: str) -> CollectiveStats:
     return CollectiveStats(by_op, sum(by_op.values()), count)
 
 
-def roofline_terms(compiled, lowered_text: str | None = None):
-    """Returns dict with the three terms + raw inputs.
+def roofline_terms(compiled, lowered_text: str | None = None, *,
+                   device_kind: str):
+    """Returns dict with the three terms + raw inputs, against the peaks
+    of ``device_kind`` (the chip the program was compiled for).
 
     FLOPs/bytes/collectives come from the scan-aware HLO analyzer
     (launch/hlo_costs.py) because ``cost_analysis()`` counts while-loop
     bodies once; the raw cost_analysis numbers are kept for reference.
     """
     from repro.launch import hlo_costs as HC
+    peaks = peaks_for(device_kind)
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):
         ca = ca[0]
@@ -116,9 +140,9 @@ def roofline_terms(compiled, lowered_text: str | None = None):
         "raw_cost_analysis": {"flops": float(ca.get("flops", 0.0)),
                               "bytes": float(ca.get("bytes accessed",
                                                     0.0))},
-        "compute_s": flops / PEAK_FLOPS,
-        "memory_s": bytes_accessed / HBM_BW,
-        "collective_s": tc["collective_bytes"] / ICI_BW,
+        "compute_s": flops / peaks.flops,
+        "memory_s": bytes_accessed / peaks.hbm_bw,
+        "collective_s": tc["collective_bytes"] / peaks.ici_bw,
     }
     dom = max(("compute_s", "memory_s", "collective_s"),
               key=lambda k: terms[k])

@@ -26,6 +26,7 @@ from repro.configs.registry import ARCH_IDS, get_config
 from repro.core import decode as D
 from repro.core import protocols as P
 from repro.distributed.sharding import AxisRules
+from repro.launch import compile_cache
 from repro.models import transformer as T
 
 
@@ -112,6 +113,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     cfg = get_config(args.arch, smoke=args.smoke)
     sampler = build_sampler(args)
     if cfg.enc_dec or cfg.frontend is not None:

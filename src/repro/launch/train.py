@@ -25,6 +25,7 @@ from repro.core import zo as Z
 from repro.data.pipeline import place_batch
 from repro.data.synthetic import BigramLM
 from repro.distributed.sharding import AxisRules, DATA_AXES
+from repro.launch import compile_cache
 from repro.launch.mesh import make_local_mesh, make_replay_mesh
 from repro.models import transformer as T
 from repro.optim.optimizers import make_optimizer
@@ -174,6 +175,7 @@ def main(argv=None):
                          "estimated round times as async arrival order")
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = make_local_mesh(args.model_parallel) if jax.device_count() > 1 \
         else None

@@ -33,17 +33,6 @@ def _fa_impl(cfg) -> str | None:
     return None
 
 
-def _fa_blocks(Sq: int, Skv: int) -> tuple[int, int]:
-    """Interpret-friendly flash tile sizes: bq must divide Sq exactly;
-    bk is free (the kernel pads Skv)."""
-    bq = Sq
-    for cand in (512, 256, 128, 64, 32, 16, 8):
-        if cand <= Sq and Sq % cand == 0:
-            bq = cand
-            break
-    return bq, min(512, Skv)
-
-
 def init_attention(pb: L.ParamBuilder, path: str, cfg: ModelConfig):
     d, hd = cfg.d_model, cfg.resolved_head_dim
     return {
@@ -272,8 +261,6 @@ def _dual_probe_attention(q, k, v, cfg: ModelConfig, *, window: int,
     common = dict(causal=True, window=window,
                   cap=cfg.attn_softcap or 0.0, scale=cfg.attn_scale,
                   impl=perturb.impl)
-    if perturb.impl != "xla":
-        common["bq"], common["bk"] = _fa_blocks(S, k.shape[1])
     if score_probe:
         sseed = O.attn_score_seed(perturb.seeds)
         off = jnp.asarray(perturb.rep, jnp.int32) * (cfg.n_heads * S)
@@ -416,10 +403,9 @@ def attention_layer(params, x, cfg: ModelConfig, rules: AxisRules, *,
             # ``perturb`` because Pallas calls have no JVP rule — the
             # clean forward is differentiated by the FO baselines and
             # the server-side update, so it stays on blocked_attention
-            bq, bk = _fa_blocks(q.shape[1], k.shape[1])
             o = O.flash_attention(q, k, v, causal=causal, window=window,
                                   cap=cfg.attn_softcap or 0.0,
-                                  scale=cfg.attn_scale, bq=bq, bk=bk,
+                                  scale=cfg.attn_scale,
                                   interpret=(fa != "pallas"))
         else:
             # seq-sharded: one q block (the whole sharded seq), kv scan

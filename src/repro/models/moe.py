@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import AxisRules, constrain, shard_map_compat
+from repro.distributed.sharding import AxisRules, constrain
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 
@@ -195,10 +195,10 @@ def moe_ep(params, x, cfg: ModelConfig, rules: AxisRules):
                                         cfg, a2a_axis="model")
         return out.reshape(Bl, Sl, d)
 
-    out = shard_map_compat(
-        local_fn, mesh,
+    out = jax.shard_map(
+        local_fn, mesh=mesh,
         in_specs=(P(None, None), wspec, wspec, dspec, xspec),
-        out_specs=xspec,
+        out_specs=xspec, check_vma=False,
     )(params["router"], params["up"], params["gate"], params["down"], x)
     out = out.astype(x.dtype)
     if "shared" in params:

@@ -26,11 +26,11 @@ def run_py(code: str, devices: int = 8, timeout: int = 600):
 def test_moe_ep_matches_xla_path():
     out = run_py(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.distributed.sharding import AxisRules
+        from repro.distributed.sharding import AxisRules, auto_mesh
         from repro.models import moe as M
         from repro.models.config import ModelConfig, MoECfg
         from repro.models.layers import ParamBuilder
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         cfg = ModelConfig(name="t", n_layers=1, d_model=32, n_heads=4,
                           n_kv_heads=4, d_ff=0, vocab=64,
                           moe=MoECfg(n_experts=8, top_k=2, d_ff_expert=16,
@@ -53,7 +53,7 @@ def test_moe_ep_matches_xla_path():
 def test_sharded_heron_step_matches_single_device():
     out = run_py(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.distributed.sharding import AxisRules
+        from repro.distributed.sharding import AxisRules, auto_mesh
         from repro.core import protocols as P, zo as Z
         from repro.models import transformer as T
         from repro.models.config import ModelConfig
@@ -88,7 +88,7 @@ def test_sharded_heron_step_matches_single_device():
                 st2, m = jax.jit(step)(st, batch)
             return float(m["loss"]), st2
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         l1, st1 = run(None)
         l2, st2 = run(mesh)
         print("LOSSES", l1, l2)
@@ -107,13 +107,13 @@ def test_dryrun_small_mesh_lower_compile():
     """A miniature of the production dry-run on an 8-device host mesh."""
     out = run_py(textwrap.dedent("""
         import jax, jax.numpy as jnp
-        from repro.distributed.sharding import AxisRules
+        from repro.distributed.sharding import AxisRules, auto_mesh
         from repro.core import protocols as P, zo as Z
         from repro.models import transformer as T
         from repro.configs.registry import get_config
         from repro.optim.optimizers import make_optimizer
         cfg = get_config("qwen2-1.5b", smoke=True)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         rules = AxisRules(mesh=mesh, enable_fsdp=False)
         api = P.lm_api(cfg, rules)
         copt = make_optimizer("zo_sgd", 1e-3)

@@ -76,9 +76,17 @@ def test_roofline_terms_structure():
     from repro.launch.roofline import roofline_terms
     x = jnp.zeros((256, 256), jnp.float32)
     c = jax.jit(lambda a: a @ a).lower(x).compile()
-    terms = roofline_terms(c)
+    terms = roofline_terms(c, device_kind="TPU v5 lite")
     for k in ("compute_s", "memory_s", "collective_s", "bottleneck",
               "roofline_step_s", "flops", "bytes_accessed"):
         assert k in terms
     assert terms["collective_bytes"] == 0.0
     assert terms["bottleneck"] in ("compute", "memory", "collective")
+
+
+def test_roofline_peaks_refuse_unknown_device_kind():
+    from repro.launch.roofline import peaks_for, roofline_terms
+    assert peaks_for("TPU v5 lite").flops == 197e12
+    c = jax.jit(lambda a: a + 1).lower(jnp.zeros((8,))).compile()
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline_terms(c, device_kind="cpu")

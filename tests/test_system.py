@@ -95,6 +95,8 @@ def test_train_driver_checkpoint_resume(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                      "src")
+    # the entry point's compile cache goes where the variable says
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
     base = [sys.executable, "-m", "repro.launch.train", "--arch",
             "qwen2-1.5b", "--smoke", "--batch", "2", "--seq", "16",
             "--ckpt-dir", str(tmp_path), "--ckpt-every", "4"]
@@ -105,3 +107,25 @@ def test_train_driver_checkpoint_resume(tmp_path):
                         capture_output=True, text=True)
     assert r2.returncode == 0, r2.stdout + r2.stderr
     assert "restored checkpoint" in r2.stdout
+    assert any((tmp_path / "jax_cache").iterdir())
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_dir):
+    """The entry points' compile cache: where the variable says, and
+    untouched, when it is set; else one fixed directory in the checkout."""
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want = str(tmp_path)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                            ".jax_cache"))
+    try:
+        assert compile_cache.enable() == want
+        assert jax.config.jax_compilation_cache_dir == (
+            prev if env_dir else want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

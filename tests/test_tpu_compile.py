@@ -1,0 +1,146 @@
+"""Compiles for a described TPU v5e (no chip attached) at GPT-2 Medium
+width: what Mosaic and the TPU compiler refuse, interpret mode never
+shows (unaligned blocks, casts Mosaic cannot lower, blocks a vmap makes
+illegal).  Each case asserts the Pallas kernel is in the compiled HLO.
+
+The topology is described inside a fixture, never at import time: only
+one process may load the TPU library, so the tests stay in this one file
+and run in the process of the worker that is given it."""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import flash_attention as FA
+from repro.kernels import zo_matmul as ZM
+
+B, S, H, D = 4, 1024, 16, 64          # GPT-2 Medium attention
+M, DM, FF = B * S, 1024, 4096         # tokens, d_model, d_ff
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _hlo(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _sds(sh, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+
+@pytest.mark.parametrize("n", [FF, 50257])
+def test_zo_matmul_compiles(one_chip, n):
+    """The single probe, at an MLP width and at GPT-2's unpadded vocab
+    (no 128-lane divisor: the wrapper pads instead)."""
+    fn = functools.partial(ZM.zo_matmul, seed=3, mu=1e-3, interpret=False)
+    assert "tpu_custom_call" in _hlo(lambda x, w: fn(x, w),
+                                     _sds(one_chip, (M, DM)),
+                                     _sds(one_chip, (DM, n)))
+
+
+def test_zo_dual_matmul_with_noise_compiles(one_chip):
+    fn = functools.partial(ZM.zo_dual_matmul, seed=3, mu_a=0.0, mu_b=1e-3,
+                           interpret=False)
+    x = _sds(one_chip, (M, DM))
+    assert "tpu_custom_call" in _hlo(lambda a, b, w: fn(a, b, w), x, x,
+                                     _sds(one_chip, (DM, FF)))
+
+
+def test_zo_dual_matmul_vmapped_over_clients_compiles(one_chip):
+    """The federated round vmaps the client forward over the cohort, so
+    each client's seed reaches the kernel batched."""
+    def one(x, w, seed):
+        return ZM.zo_dual_matmul(x, x, w, seed, 0.0, 1e-3,
+                                 interpret=False)[1]
+
+    assert "tpu_custom_call" in _hlo(
+        jax.vmap(one), _sds(one_chip, (4, 2 * S, DM)),
+        _sds(one_chip, (4, DM, FF)), _sds(one_chip, (4,), jnp.int32))
+
+
+@pytest.mark.parametrize("seq", [S, 1001])
+def test_flash_attention_compiles(one_chip, seq):
+    q = _sds(one_chip, (B, seq, H, D))
+    fn = functools.partial(FA.flash_attention, interpret=False)
+    assert "tpu_custom_call" in _hlo(fn, q, q, q)
+
+
+@pytest.mark.parametrize("probe", ["weights", "scores"])
+def test_zo_dual_flash_attention_compiles(one_chip, probe):
+    q = _sds(one_chip, (B, S, H, D))
+    if probe == "weights":
+        def fn(qa, qb, k, v, kb, vb):
+            return FA.zo_dual_flash_attention(qa, qb, k, v, kb, vb,
+                                              perturb_b=False,
+                                              interpret=False)
+        args = (q,) * 6
+    else:
+        def fn(qa, qb, k, v):
+            return FA.zo_dual_flash_attention(qa, qb, k, v, seed=5,
+                                              mu_b=1e-3, interpret=False)
+        args = (q,) * 4
+    assert "tpu_custom_call" in _hlo(fn, *args)
+
+
+def test_gpt2_medium_fed_round_compiles(one_chip, monkeypatch):
+    """The jitted HERON round that ``chip_smoke.py`` runs (lean uplink,
+    kernel client forward, its cohort and micro-batch) compiles with the
+    kernels in it and fits a 16 GiB chip."""
+    import importlib.util
+    import os
+
+    from repro.configs.gpt2 import gpt2_medium
+    from repro.core import protocols as P
+    from repro.core import zo as Z
+    from repro.distributed.sharding import AxisRules
+    from repro.kernels import ops as O
+    from repro.models import transformer as T
+    from repro.optim.optimizers import make_optimizer
+
+    path = os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    # the host is a CPU: steer the backend choice to the chip's
+    monkeypatch.setattr(O, "default_forward_impl", lambda: "pallas")
+    monkeypatch.setattr(O, "_interpret", lambda: False)
+    cfg = gpt2_medium().replace(forward_impl="kernel")
+    sopt = make_optimizer("adamw", 1e-4)
+    fn = P.make_fed_round(
+        P.lm_api(cfg, AxisRules(mesh=None)), "heron", Z.ZOConfig(),
+        P.FedConfig(n_clients=smoke.CLIENTS, h=1),
+        make_optimizer("zo_sgd", 1e-4), sopt, uplink="seed_replay",
+        client_lr=1e-4)
+    state = jax.eval_shape(lambda p: {
+        "client": p["client"], "server": p["server"],
+        "opt_server": sopt.init(p["server"])},
+        T.init_lm(None, cfg, mode="shape"))
+    state = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), state)
+    tok = _sds(one_chip, (smoke.CLIENTS, 1, smoke.MICRO_BATCH, smoke.SEQ),
+               jnp.int32)
+    c = jax.jit(fn, donate_argnums=0).lower(
+        state, {"inputs": tok, "labels": tok},
+        _sds(one_chip, (2,), jnp.uint32)).compile()
+    assert "tpu_custom_call" in c.as_text()
+    ma = c.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 14 * 2 ** 30
