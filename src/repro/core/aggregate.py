@@ -122,73 +122,75 @@ def _replay_engine(global_params, tokens, scales, make_direction,
       continues the same scan carry and is bit-exact vs one-shot;
       sharded chunking reduces per chunk (allclose, not bitwise).
     """
-    shapes = jax.tree.map(
-        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), global_params)
+    with jax.named_scope("heron_replay"):
+        shapes = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), global_params)
 
-    def scan_into(acc, toks, scs):
-        def step(a, ts):
-            t, s = ts
-            u = make_direction(t, shapes)
-            return jax.tree.map(lambda ai, ul: ai + s * ul, a, u), None
-        acc, _ = jax.lax.scan(step, acc, (toks, scs))
-        return acc
+        def scan_into(acc, toks, scs):
+            def step(a, ts):
+                t, s = ts
+                u = make_direction(t, shapes)
+                return jax.tree.map(lambda ai, ul: ai + s * ul, a, u), None
+            acc, _ = jax.lax.scan(step, acc, (toks, scs))
+            return acc
 
-    def zeros_acc():
-        return jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32),
-                            shapes)
+        def zeros_acc():
+            return jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32),
+                                shapes)
 
-    m = scales.shape[0]
-    if shard == "none":
+        m = scales.shape[0]
+        if shard == "none":
+            if chunk is None:
+                return _apply_acc(global_params,
+                                  scan_into(zeros_acc(), tokens, scales))
+            n_chunks = -(-m // chunk)
+            tokens = _pad_leading(tokens, n_chunks * chunk)
+            scales = _pad_leading(scales, n_chunks * chunk)
+            step_fn = jax.jit(scan_into, donate_argnums=0)
+            acc = zeros_acc()
+            for c in range(n_chunks):
+                sl = slice(c * chunk, (c + 1) * chunk)
+                acc = step_fn(acc, tokens[sl], scales[sl])
+            return _apply_acc(global_params, acc)
+
+        mesh = _resolve_replay_mesh(shard, mesh)
+        n_sh = mesh.shape[shard]
+        tok_spec = P(shard, *([None] * (tokens.ndim - 1)))
+
+        def shard_delta(toks, scs):
+            def body(tl, sl):
+                acc = scan_into(zeros_acc(), tl, sl)
+                return jax.tree.map(lambda a: jax.lax.psum(a, shard), acc)
+            return jax.shard_map(body, mesh=mesh,
+                                 in_specs=(tok_spec, P(shard)),
+                                 out_specs=P(), check_vma=False)(toks, scs)
+
         if chunk is None:
+            m_pad = -(-m // n_sh) * n_sh
             return _apply_acc(global_params,
-                              scan_into(zeros_acc(), tokens, scales))
-        n_chunks = -(-m // chunk)
-        tokens = _pad_leading(tokens, n_chunks * chunk)
-        scales = _pad_leading(scales, n_chunks * chunk)
-        step_fn = jax.jit(scan_into, donate_argnums=0)
+                              shard_delta(_pad_leading(tokens, m_pad),
+                                          _pad_leading(scales, m_pad)))
+
+        per_dev = -(-m // (n_sh * chunk)) * chunk
+        n_chunks = per_dev // chunk
+        toks = _pad_leading(tokens, per_dev * n_sh)
+        scs = _pad_leading(scales, per_dev * n_sh)
+        # device-major -> chunk-major, so each chunk is one contiguous slab
+        # holding `chunk` consecutive entries of every device's sub-stream
+        toks = jnp.moveaxis(
+            toks.reshape((n_sh, n_chunks, chunk) + toks.shape[1:]), 1, 0)
+        scs = jnp.moveaxis(scs.reshape(n_sh, n_chunks, chunk), 1, 0)
+
+        def chunk_step(acc, tc, sc):
+            d = shard_delta(tc.reshape((n_sh * chunk,) + tc.shape[2:]),
+                            sc.reshape(-1))
+            return jax.tree.map(jnp.add, acc, d)
+
+        step_fn = jax.jit(chunk_step, donate_argnums=0)
         acc = zeros_acc()
         for c in range(n_chunks):
-            sl = slice(c * chunk, (c + 1) * chunk)
-            acc = step_fn(acc, tokens[sl], scales[sl])
+            acc = step_fn(acc, toks[c], scs[c])
         return _apply_acc(global_params, acc)
-
-    mesh = _resolve_replay_mesh(shard, mesh)
-    n_sh = mesh.shape[shard]
-    tok_spec = P(shard, *([None] * (tokens.ndim - 1)))
-
-    def shard_delta(toks, scs):
-        def body(tl, sl):
-            acc = scan_into(zeros_acc(), tl, sl)
-            return jax.tree.map(lambda a: jax.lax.psum(a, shard), acc)
-        return jax.shard_map(body, mesh=mesh, in_specs=(tok_spec, P(shard)),
-                             out_specs=P(), check_vma=False)(toks, scs)
-
-    if chunk is None:
-        m_pad = -(-m // n_sh) * n_sh
-        return _apply_acc(global_params,
-                          shard_delta(_pad_leading(tokens, m_pad),
-                                      _pad_leading(scales, m_pad)))
-
-    per_dev = -(-m // (n_sh * chunk)) * chunk
-    n_chunks = per_dev // chunk
-    toks = _pad_leading(tokens, per_dev * n_sh)
-    scs = _pad_leading(scales, per_dev * n_sh)
-    # device-major -> chunk-major, so each chunk is one contiguous slab
-    # holding `chunk` consecutive entries of every device's sub-stream
-    toks = jnp.moveaxis(
-        toks.reshape((n_sh, n_chunks, chunk) + toks.shape[1:]), 1, 0)
-    scs = jnp.moveaxis(scs.reshape(n_sh, n_chunks, chunk), 1, 0)
-
-    def chunk_step(acc, tc, sc):
-        d = shard_delta(tc.reshape((n_sh * chunk,) + tc.shape[2:]),
-                        sc.reshape(-1))
-        return jax.tree.map(jnp.add, acc, d)
-
-    step_fn = jax.jit(chunk_step, donate_argnums=0)
-    acc = zeros_acc()
-    for c in range(n_chunks):
-        acc = step_fn(acc, toks[c], scs[c])
-    return _apply_acc(global_params, acc)
 
 
 def _raw_key_data(keys):

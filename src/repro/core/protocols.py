@@ -70,15 +70,14 @@ def lm_api(cfg: ModelConfig, rules: AxisRules) -> ModelAPI:
     def client_loss(cp, batch):
         s, _ = T.client_forward(cp, cfg, rules, batch["inputs"],
                                 batch.get("positions"))
-        logits = T.aux_forward(cp, cfg, rules, s, batch.get("positions"))
-        lbl = batch.get("aux_labels", batch["labels"])
-        return T.lm_loss(logits, lbl, cfg.vocab), s
+        return aux_loss(cp, s, batch), s
 
     def aux_loss(cp, smashed, batch):
-        logits = T.aux_forward(cp, cfg, rules, smashed,
-                               batch.get("positions"))
-        lbl = batch.get("aux_labels", batch["labels"])
-        return T.lm_loss(logits, lbl, cfg.vocab)
+        with jax.named_scope("heron_aux_head"):
+            logits = T.aux_forward(cp, cfg, rules, smashed,
+                                   batch.get("positions"))
+            lbl = batch.get("aux_labels", batch["labels"])
+            return T.lm_loss(logits, lbl, cfg.vocab)
 
     def server_loss(sp, cp_const, smashed, batch):
         logits, _ = T.server_forward(
@@ -107,11 +106,12 @@ def lm_api(cfg: ModelConfig, rules: AxisRules) -> ModelAPI:
             s2, _ = T.client_forward(cp, cfg, rules, batch["inputs"], pos,
                                      perturb=pz)
             pos2 = None if pos is None else jnp.concatenate([pos, pos], 0)
-            logits2 = T.aux_forward(cp, cfg, rules, s2, pos2, perturb=pz)
-            lbl = batch.get("aux_labels", batch["labels"])
             B = batch["inputs"].shape[0]
-            l0 = T.lm_loss(logits2[:B], lbl, cfg.vocab)
-            lp = T.lm_loss(logits2[B:], lbl, cfg.vocab)
+            with jax.named_scope("heron_aux_head"):
+                logits2 = T.aux_forward(cp, cfg, rules, s2, pos2, perturb=pz)
+                lbl = batch.get("aux_labels", batch["labels"])
+                l0 = T.lm_loss(logits2[:B], lbl, cfg.vocab)
+                lp = T.lm_loss(logits2[B:], lbl, cfg.vocab)
             return l0, lp, s2[:B]
 
     seed_pred = None
@@ -125,10 +125,12 @@ def lm_api(cfg: ModelConfig, rules: AxisRules) -> ModelAPI:
 def cnn_api(cfg: CNN.CNNConfig) -> ModelAPI:
     def client_loss(cp, batch):
         s = CNN.client_forward(cp, batch["inputs"], cfg)
-        return CNN.xent(CNN.aux_logits(cp, s, cfg), batch["labels"]), s
+        return aux_loss(cp, s, batch), s
 
     def aux_loss(cp, smashed, batch):
-        return CNN.xent(CNN.aux_logits(cp, smashed, cfg), batch["labels"])
+        with jax.named_scope("heron_aux_head"):
+            return CNN.xent(CNN.aux_logits(cp, smashed, cfg),
+                            batch["labels"])
 
     def server_loss(sp, cp_const, smashed, batch):
         return CNN.xent(CNN.server_logits(sp, smashed, cfg),
@@ -144,10 +146,11 @@ def cnn_api(cfg: CNN.CNNConfig) -> ModelAPI:
         def client_dual_loss(cp, batch, seeds, mu):
             pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl=impl)
             s2 = CNN.client_forward(cp, batch["inputs"], cfg, pz)
-            logits2 = CNN.aux_logits(cp, s2, cfg, pz)
             B = batch["inputs"].shape[0]
-            l0 = CNN.xent(logits2[:B], batch["labels"])
-            lp = CNN.xent(logits2[B:], batch["labels"])
+            with jax.named_scope("heron_aux_head"):
+                logits2 = CNN.aux_logits(cp, s2, cfg, pz)
+                l0 = CNN.xent(logits2[:B], batch["labels"])
+                lp = CNN.xent(logits2[B:], batch["labels"])
             return l0, lp, s2[:B]
 
     return ModelAPI(client_loss, aux_loss, server_loss, joint_loss,
@@ -450,36 +453,37 @@ def _make_cohort_trajectory(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
                                       uplink, client_lr, kernel_client)
 
     def run(state_client, round_batch, key):
-        N, h = fed.n_clients, fed.h
-        cp0 = jax.tree.map(
-            lambda p: jnp.broadcast_to(p[None], (N,) + p.shape),
-            state_client)
-        oc0 = jax.vmap(client_opt.init)(cp0)
-        # one base key per client; local step m folds m on top and
-        # zo_gradient folds the pair index on top of that — the same
-        # (client, step, pair) stream seed_replay_aggregate re-derives.
-        if kernel_client:
-            client_keys = O.fold_seed(Z.seed_from_key(key), jnp.arange(N))
-        else:
-            client_keys = Z.fold_in_range(key, N)
-
-        def step_m(carry, m):
-            cps, ocs = carry
-            batch_m = jax.tree.map(lambda x: jnp.take(x, m, axis=1),
-                                   round_batch)
+        with jax.named_scope("heron_cohort"):
+            N, h = fed.n_clients, fed.h
+            cp0 = jax.tree.map(
+                lambda p: jnp.broadcast_to(p[None], (N,) + p.shape),
+                state_client)
+            oc0 = jax.vmap(client_opt.init)(cp0)
+            # one base key per client; local step m folds m on top and
+            # zo_gradient folds the pair index on top of that — the same
+            # (client, step, pair) stream seed_replay_aggregate re-derives.
             if kernel_client:
-                keys = O.fold_seed(client_keys, m)
+                client_keys = O.fold_seed(Z.seed_from_key(key), jnp.arange(N))
             else:
-                keys = jax.vmap(
-                    lambda ck: jax.random.fold_in(ck, m))(client_keys)
-            cps, ocs, smashed, losses, coeffs = jax.vmap(
-                local_update, in_axes=(0, 0, 0, 0))(cps, ocs, batch_m,
-                                                    keys)
-            return (cps, ocs), (smashed, losses, coeffs)
+                client_keys = Z.fold_in_range(key, N)
 
-        (cps, _), (smashed_all, losses, coeffs_all) = jax.lax.scan(
-            step_m, (cp0, oc0), jnp.arange(h))
-        return client_keys, cps, smashed_all, losses, coeffs_all
+            def step_m(carry, m):
+                cps, ocs = carry
+                batch_m = jax.tree.map(lambda x: jnp.take(x, m, axis=1),
+                                       round_batch)
+                if kernel_client:
+                    keys = O.fold_seed(client_keys, m)
+                else:
+                    keys = jax.vmap(
+                        lambda ck: jax.random.fold_in(ck, m))(client_keys)
+                cps, ocs, smashed, losses, coeffs = jax.vmap(
+                    local_update, in_axes=(0, 0, 0, 0))(cps, ocs, batch_m,
+                                                        keys)
+                return (cps, ocs), (smashed, losses, coeffs)
+
+            (cps, _), (smashed_all, losses, coeffs_all) = jax.lax.scan(
+                step_m, (cp0, oc0), jnp.arange(h))
+            return client_keys, cps, smashed_all, losses, coeffs_all
 
     return run, kernel_client
 
@@ -496,33 +500,34 @@ def _make_server_updates(api: ModelAPI, fed: FedConfig,
     upload_ms = [m for m in range(fed.h) if m % fed.upload_every == 0]
 
     def apply(sp, os_, cp_const, round_batch, smashed_all, cids):
-        s_losses = []
-        for m in upload_ms:
-            batch_m = jax.tree.map(lambda x: x[:, m], round_batch)
-            smashed_m = jax.tree.map(lambda s: s[m], smashed_all)
-            if fed.quantize_uplink:
-                from repro.core.split import (dequantize_smashed,
-                                              quantize_smashed)
-                qm, sc = quantize_smashed(smashed_m)
-                smashed_m = dequantize_smashed(qm, sc, smashed_m.dtype)
+        with jax.named_scope("heron_server_fo"):
+            s_losses = []
+            for m in upload_ms:
+                batch_m = jax.tree.map(lambda x: x[:, m], round_batch)
+                smashed_m = jax.tree.map(lambda s: s[m], smashed_all)
+                if fed.quantize_uplink:
+                    from repro.core.split import (dequantize_smashed,
+                                                  quantize_smashed)
+                    qm, sc = quantize_smashed(smashed_m)
+                    smashed_m = dequantize_smashed(qm, sc, smashed_m.dtype)
 
-            def server_client_step(carry, i):
-                spx, osx = carry
-                sm = jax.tree.map(lambda s: jnp.take(s, i, axis=0),
-                                  smashed_m)
-                bt = jax.tree.map(lambda x: jnp.take(x, i, axis=0),
-                                  batch_m)
-                sl, g = jax.value_and_grad(
-                    lambda p: api.server_loss(p, cp_const,
-                                              jax.lax.stop_gradient(sm),
-                                              bt))(spx)
-                spx, osx = server_opt.update(g, osx, spx)
-                return (spx, osx), sl
+                def server_client_step(carry, i):
+                    spx, osx = carry
+                    sm = jax.tree.map(lambda s: jnp.take(s, i, axis=0),
+                                      smashed_m)
+                    bt = jax.tree.map(lambda x: jnp.take(x, i, axis=0),
+                                      batch_m)
+                    sl, g = jax.value_and_grad(
+                        lambda p: api.server_loss(p, cp_const,
+                                                  jax.lax.stop_gradient(sm),
+                                                  bt))(spx)
+                    spx, osx = server_opt.update(g, osx, spx)
+                    return (spx, osx), sl
 
-            (sp, os_), sls = jax.lax.scan(server_client_step, (sp, os_),
-                                          cids)
-            s_losses.append(sls)
-        return sp, os_, s_losses
+                (sp, os_), sls = jax.lax.scan(server_client_step, (sp, os_),
+                                              cids)
+                s_losses.append(sls)
+            return sp, os_, s_losses
 
     return apply
 

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import hlo_scopes
 from repro.kernels import flash_attention as FA
 from repro.kernels import zo_matmul as ZM
 
@@ -105,7 +106,8 @@ def test_zo_dual_flash_attention_compiles(one_chip, probe):
 def test_gpt2_medium_fed_round_compiles(one_chip, monkeypatch):
     """The jitted HERON round that ``chip_smoke.py`` runs (lean uplink,
     kernel client forward, its cohort and micro-batch) compiles with the
-    kernels in it and fits a 16 GiB chip."""
+    kernels in it, every kernel under the cohort's scope and every matrix
+    product under one phase's, and fits a 16 GiB chip."""
     import importlib.util
     import os
 
@@ -141,6 +143,13 @@ def test_gpt2_medium_fed_round_compiles(one_chip, monkeypatch):
     c = jax.jit(fn, donate_argnums=0).lower(
         state, {"inputs": tok, "labels": tok},
         _sds(one_chip, (2,), jnp.uint32)).compile()
-    assert "tpu_custom_call" in c.as_text()
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    # the compiler's own custom-calls (buffers, bitcasts) carry no op_name
+    assert hlo_scopes.phase_faults(text, targets={"tpu_custom_call"}) == []
+    kernels = [op for _, rest, op in hlo_scopes.instructions(text)
+               if 'custom_call_target="tpu_custom_call"' in rest]
+    assert kernels and all("heron_cohort" in hlo_scopes.scopes(op)
+                           for op in kernels)
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 14 * 2 ** 30
