@@ -30,9 +30,26 @@ leading scan axis (reps, K, N) treats rep r as rows [r*K, (r+1)*K) of
 one canonical (reps*K, N) noise field, so sliced-per-rep kernel calls
 and whole-leaf replay see the same stream.
 
+The MXU feed: the v5e MXU multiplies bf16, and an f32 x f32 ``jnp.dot``
+in a kernel runs on it as one bf16 pass that rounds both operands, which
+would round ``W + mu*U`` to bf16 and lose most of a small perturbation.
+When the operands are bf16, the kernels keep them bf16: the clean stream
+``x @ W`` is one bf16 pass, and the perturbed stream splits the f32
+``W + mu*U`` of each tile into three bf16 limbs ``hi = bf16(w')``,
+``mid = bf16(w' - hi)``, ``lo = bf16(w' - hi - mid)`` and sums three
+passes in f32.  Nothing is lost: an
+f32 significand (24 bits) is at most three bf16 significands (8 bits
+each), so the limbs hold ``w'`` exactly, and a bf16 x bf16 product is
+exact in f32: the only roundings are those of the f32 sums, as in an
+exact f32 contraction.  Other operand dtypes contract in f32.
+
 Grid: (nm, nn, nk) with the k loop innermost; f32 VMEM scratch
 accumulates partial products across k steps (TPU grid iteration is
-sequential, so scratch carries state).
+sequential, so scratch carries state).  Blocks a caller leaves out come
+from the shape (:func:`choose_blocks`): 128-aligned lane blocks of 256
+to 512 that divide K and N (or whole axes), and as many rows as fit
+:data:`VMEM_LIMIT`, up to the whole M, so that each W tile's noise and
+limbs are made once for many rows.
 """
 from __future__ import annotations
 
@@ -52,9 +69,9 @@ def tile(dim: int, pref: int, align: int) -> tuple[int, int]:
     The whole axis when it fits in one block; else the largest multiple
     of ``align`` in [pref/2, pref] that divides it; else ``pref``
     (rounded down to ``align``) with the axis padded up to whole blocks.
-    Compiled kernels pass Mosaic's tiling (8 rows, 128 lanes) as
+    Compiled kernels pass Mosaic's tiling (8 or 16 rows, 128 lanes) as
     ``align``, so no block they see is unaligned; interpret mode passes
-    1 and keeps the caller's exact divisor blocks.
+    1 for the blocks a caller gives and keeps its exact divisors.
     """
     if dim <= pref:
         return dim, dim
@@ -122,6 +139,100 @@ def uniform_noise_at(seed, rows, cols):
 
 
 # ---------------------------------------------------------------------------
+# the MXU feed and the blocks, shared by both matmul kernels
+# ---------------------------------------------------------------------------
+
+# Scoped VMEM the matmul kernels may ask Mosaic for (the v5e has 128 MiB
+# per core), and so the budget of the row blocks: on one TPU v5e the dual
+# kernel at the fed cells' shapes ran as fast with 2048 rows as with
+# 4096, which took twice the VMEM and three times the compile.
+VMEM_LIMIT = 48 * 2 ** 20
+
+
+def _limbs(w):
+    """Three bf16 limbs whose sum is the f32 ``w`` exactly: each limb
+    rounds what the limbs before it left over, and an f32 significand
+    (24 bits) is at most three bf16 significands (8 bits each) long."""
+    hi = w.astype(jnp.bfloat16)
+    rest = w - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _tile_product(x, w, mu, u):
+    """One probe stream's partial product ``x @ (w + mu*u)`` for one tile,
+    in f32 (``u is None``: the clean ``x @ w``).
+
+    bf16 operands feed the MXU at its native rate and lose nothing: a
+    bf16 x bf16 product is exact in f32, so ``x @ w`` is one bf16 pass,
+    and the f32 ``w + mu*u`` enters as its three exact bf16 limbs, three
+    passes summed in f32.  Other dtypes contract in f32."""
+    f32 = jnp.float32
+    if x.dtype == w.dtype == jnp.bfloat16:
+        if u is None:
+            return jnp.dot(x, w, preferred_element_type=f32)
+        hi, mid, lo = _limbs(w.astype(f32) + mu * u)
+        return jnp.dot(x, hi, preferred_element_type=f32) + (
+            jnp.dot(x, mid, preferred_element_type=f32)
+            + jnp.dot(x, lo, preferred_element_type=f32))
+    w = w.astype(f32)
+    if u is not None:
+        w = w + mu * u
+    return jnp.dot(x.astype(f32), w, preferred_element_type=f32)
+
+
+def vmem_bytes(bm: int, bk: int, bn: int, itemsize: int) -> int:
+    """Scoped VMEM of the dual kernel at blocks (bm, bk, bn), counted
+    from above: xa, xb, w, ya and yb double-buffered, the two f32
+    accumulators, and one step's f32 temporaries (three dot results,
+    the noise tile with its hash words, ``w + mu*U`` and its limbs)."""
+    io = 2 * (2 * bm * bk + bk * bn + 2 * bm * bn) * itemsize
+    acc = 2 * bm * bn * 4
+    temps = 3 * bm * bn * 4 + 8 * bk * bn * 4
+    return io + acc + temps
+
+
+def _lane_block(dim: int) -> int:
+    """bn or bk: the whole axis up to 512; else a 128-aligned divisor in
+    [256, 512]; else the whole axis up to 1024 (576 = 3·3·64, an im2col
+    width, has no such divisor); else 512, padded."""
+    if dim <= 512:
+        return dim
+    for b in (512, 384, 256):
+        if dim % b == 0:
+            return b
+    return dim if dim <= 1024 else 512
+
+
+def choose_blocks(M: int, K: int, N: int, itemsize: int):
+    """(bm, bk, bn) for an (M, K) @ (K, N) matmul kernel: lane blocks
+    from :func:`_lane_block`, and the most rows a block can take within
+    :data:`VMEM_LIMIT`, whole M first, so that each W tile's noise and
+    limbs serve as many rows as fit.  The single probe takes the dual
+    kernel's blocks, so that a dual pass equals two single passes bit
+    for bit."""
+    bn, bk = _lane_block(N), _lane_block(K)
+    for pref in (M, 4096, 2048, 1024, 512, 256, 128):
+        bm = tile(M, pref, 16)[0]
+        if vmem_bytes(bm, bk, bn, itemsize) <= VMEM_LIMIT:
+            break
+    return bm, bk, bn
+
+
+def _matmul_tiles(M, N, K, bm, bn, bk, itemsize, interpret):
+    """Per-axis (block, padded extent).  A block the caller leaves out
+    is chosen (:func:`choose_blocks`) and aligned as on the chip, so
+    interpret mode runs the compiled path's grid; a block given keeps
+    Mosaic's tiling (8 rows, 128 lanes) on the compiled path only."""
+    cm, ck, cn = choose_blocks(M, K, N, itemsize)
+    row, lane = (1, 1) if interpret else (8, 128)
+    return (tile(M, cm, 16) if bm is None else tile(M, bm, row),
+            tile(N, cn, 128) if bn is None else tile(N, bn, lane),
+            tile(K, ck, 128) if bk is None else tile(K, bk, lane))
+
+
+# ---------------------------------------------------------------------------
 # single-probe kernel: y = x @ (W + mu*U)
 # ---------------------------------------------------------------------------
 
@@ -135,14 +246,12 @@ def _zo_matmul_kernel(seed_ref, mu_ref, off_ref, x_ref, w_ref, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = w_ref[...].astype(jnp.float32)
+    u = None
     if gen_noise:
         u = uniform_noise(seed_ref[0, 0], (bk, bn),
                           row_offset=off_ref[0, 0] + ki * bk,
                           col_offset=ni * bn)
-        w = w + mu_ref[0, 0] * u
-    acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += _tile_product(x_ref[...], w_ref[...], mu_ref[0, 0], u)
 
     @pl.when(ki == nk - 1)
     def _done():
@@ -151,8 +260,9 @@ def _zo_matmul_kernel(seed_ref, mu_ref, off_ref, x_ref, w_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk",
                                              "interpret", "perturb"))
-def zo_matmul(x, w, seed, mu, *, row_offset=0, bm: int = 128, bn: int = 128,
-              bk: int = 128, interpret: bool = True, perturb: bool = True):
+def zo_matmul(x, w, seed, mu, *, row_offset=0, bm: int | None = None,
+              bn: int | None = None, bk: int | None = None,
+              interpret: bool = True, perturb: bool = True):
     """y = x @ (W + mu*U(seed)); x: (M, K), w: (K, N).
 
     ``interpret=True`` executes on CPU for validation; on TPU pass
@@ -160,13 +270,14 @@ def zo_matmul(x, w, seed, mu, *, row_offset=0, bm: int = 128, bn: int = 128,
     blocked matmul (the clean forward of the two-point estimator).
     ``row_offset`` shifts the global noise rows (stacked scan leaves).
     ``bm``/``bn``/``bk`` are preferred block sizes (see :func:`tile`);
-    padded rows/columns are zeros and are sliced off the result.
+    those left out are chosen from the shape (:func:`choose_blocks`).
+    Padded rows/columns are zeros and are sliced off the result.
     """
     M, K = x.shape
     K2, N = w.shape
     assert K == K2
-    (bm, Mp), (bn, Np), (bk, Kp) = _matmul_tiles(M, N, K, bm, bn, bk,
-                                                 interpret)
+    (bm, Mp), (bn, Np), (bk, Kp) = _matmul_tiles(
+        M, N, K, bm, bn, bk, x.dtype.itemsize, interpret)
     x, w = pad_to(x, (Mp, Kp)), pad_to(w, (Kp, Np))
     nm, nn, nk = Mp // bm, Np // bn, Kp // bk
     seed_arr = jnp.asarray([[seed]], jnp.int32)
@@ -187,16 +298,10 @@ def zo_matmul(x, w, seed, mu, *, row_offset=0, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(seed_arr, mu_arr, off_arr, x, w)
     return y[:M, :N]
-
-
-def _matmul_tiles(M, N, K, bm, bn, bk, interpret):
-    """Per-axis (block, padded extent): rows align to 8 and lanes to 128
-    on the compiled path."""
-    row, lane = (1, 1) if interpret else (8, 128)
-    return tile(M, bm, row), tile(N, bn, lane), tile(K, bk, lane)
 
 
 # ---------------------------------------------------------------------------
@@ -214,17 +319,16 @@ def _zo_dual_kernel(seed_ref, mu_ref, off_ref, xa_ref, xb_ref, w_ref,
         acca_ref[...] = jnp.zeros_like(acca_ref)
         accb_ref[...] = jnp.zeros_like(accb_ref)
 
-    w = w_ref[...].astype(jnp.float32)
+    w = w_ref[...]
+    u = None
     if perturb_a or perturb_b:
         u = uniform_noise(seed_ref[0, 0], (bk, bn),
                           row_offset=off_ref[0, 0] + ki * bk,
                           col_offset=ni * bn)
-    wa = w + mu_ref[0, 0] * u if perturb_a else w
-    wb = w + mu_ref[0, 1] * u if perturb_b else w
-    acca_ref[...] += jnp.dot(xa_ref[...].astype(jnp.float32), wa,
-                             preferred_element_type=jnp.float32)
-    accb_ref[...] += jnp.dot(xb_ref[...].astype(jnp.float32), wb,
-                             preferred_element_type=jnp.float32)
+    acca_ref[...] += _tile_product(xa_ref[...], w, mu_ref[0, 0],
+                                   u if perturb_a else None)
+    accb_ref[...] += _tile_product(xb_ref[...], w, mu_ref[0, 1],
+                                   u if perturb_b else None)
 
     @pl.when(ki == nk - 1)
     def _done():
@@ -235,8 +339,9 @@ def _zo_dual_kernel(seed_ref, mu_ref, off_ref, xa_ref, xb_ref, w_ref,
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
                                              "perturb_a", "perturb_b"))
 def zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b, *, row_offset=0,
-                   bm: int = 128, bn: int = 128, bk: int = 128,
-                   interpret: bool = True, perturb_a: bool = False,
+                   bm: int | None = None, bn: int | None = None,
+                   bk: int | None = None, interpret: bool = True,
+                   perturb_a: bool = False,
                    perturb_b: bool = True):
     """(ya, yb) = (xa @ (W + mu_a*U), xb @ (W + mu_b*U)) in ONE pass.
 
@@ -255,8 +360,8 @@ def zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b, *, row_offset=0,
     assert xb.shape == xa.shape, (xa.shape, xb.shape)
     K2, N = w.shape
     assert K == K2
-    (bm, Mp), (bn, Np), (bk, Kp) = _matmul_tiles(M, N, K, bm, bn, bk,
-                                                 interpret)
+    (bm, Mp), (bn, Np), (bk, Kp) = _matmul_tiles(
+        M, N, K, bm, bn, bk, xa.dtype.itemsize, interpret)
     xa, xb = pad_to(xa, (Mp, Kp)), pad_to(xb, (Mp, Kp))
     w = pad_to(w, (Kp, Np))
     nm, nn, nk = Mp // bm, Np // bn, Kp // bk
@@ -284,6 +389,7 @@ def zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b, *, row_offset=0,
                    jax.ShapeDtypeStruct((Mp, Np), xb.dtype)],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(seed_arr, mu_arr, off_arr, xa, xb, w)
     return ya[:M, :N], yb[:M, :N]
@@ -309,7 +415,7 @@ def zo_noise(w_shape_like, seed, *, bn: int = 128, bk: int = 128,
     stream is addressed by global coordinates, the result is independent
     of ``bn``/``bk`` and equals ``uniform_noise(seed, w.shape)``."""
     K, N = w_shape_like.shape
-    _, (bn, Np), (bk, Kp) = _matmul_tiles(1, N, K, 1, bn, bk, interpret)
+    _, (bn, Np), (bk, Kp) = _matmul_tiles(1, N, K, 1, bn, bk, 4, interpret)
     nn, nk = Np // bn, Kp // bk
     seed_arr = jnp.asarray([[seed]], jnp.int32)
     u = pl.pallas_call(

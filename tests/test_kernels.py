@@ -90,14 +90,23 @@ def test_rg_lru_scan_sweep(b, s, w, bt, bw):
 
 # --- fused dual probe -------------------------------------------------------
 
-@pytest.mark.parametrize("m,k,n", [(32, 128, 128), (64, 256, 128),
-                                   (16, 96, 64)])
-def test_zo_dual_matmul_matches_two_single_passes(m, k, n):
-    """One fused pass == two independent zo_matmul calls, bitwise."""
-    xa = jax.random.normal(jax.random.PRNGKey(0), (m, k))
-    xb = jax.random.normal(jax.random.PRNGKey(1), (m, k))
-    w = jax.random.normal(jax.random.PRNGKey(2), (k, n))
-    bs = dict(bm=16, bn=32, bk=32)
+_BS = dict(bm=16, bn=32, bk=32)
+
+
+@pytest.mark.parametrize("m,k,n,dtype,bs", [
+    pytest.param(32, 128, 128, jnp.float32, _BS, id="32-128-128"),
+    pytest.param(64, 256, 128, jnp.float32, _BS, id="64-256-128"),
+    pytest.param(16, 96, 64, jnp.float32, _BS, id="16-96-64"),
+    pytest.param(64, 256, 128, jnp.bfloat16, _BS, id="bf16-64-256-128"),
+    pytest.param(16, 96, 64, jnp.bfloat16, _BS, id="bf16-16-96-64"),
+    pytest.param(48, 640, 256, jnp.bfloat16, {}, id="bf16-chosen-48-640-256"),
+])
+def test_zo_dual_matmul_matches_two_single_passes(m, k, n, dtype, bs):
+    """One fused pass == two independent zo_matmul calls, bitwise, for
+    the f32 contraction and for the bf16 limb feed alike."""
+    xa = jax.random.normal(jax.random.PRNGKey(0), (m, k), dtype)
+    xb = jax.random.normal(jax.random.PRNGKey(1), (m, k), dtype)
+    w = jax.random.normal(jax.random.PRNGKey(2), (k, n), dtype)
     ya, yb = ops.zo_dual_matmul(xa, xb, w, 11, 0.0, 0.05,
                                 impl="interpret", **bs)
     ya1 = ops.zo_matmul(xa, w, 11, 0.0, impl="interpret", perturb=False,
@@ -118,6 +127,69 @@ def test_zo_dual_matmul_vs_ref_oracle():
                                rtol=5e-5, atol=5e-4)
     np.testing.assert_allclose(np.asarray(yb), np.asarray(rb),
                                rtol=5e-5, atol=5e-4)
+
+
+def _float64_dual(xa, xb, w, seed, mu_a, mu_b, perturb_a):
+    """The dual probe in float64 on the operands' own values, and the
+    bound on the f32 accumulation error (K·2^-24 · |x| @ |w'|)."""
+    u = np.asarray(ops.uniform_noise(seed, w.shape), np.float64)
+    w64 = np.asarray(w, np.float64)
+    out = []
+    for x, mu, pert in ((xa, mu_a, perturb_a), (xb, mu_b, True)):
+        x64 = np.asarray(x, np.float64)
+        wp = w64 + mu * u if pert else w64
+        out.append((x64 @ wp,
+                    x.shape[1] * 2.0 ** -24 * (np.abs(x64) @ np.abs(wp))))
+    return out
+
+
+@pytest.mark.parametrize("m,k,n,bs", [
+    (64, 256, 128, _BS),
+    (32, 128, 64, dict(bm=32)),
+    (40, 27, 64, {}),          # chosen: whole axes (an im2col conv's K, N)
+    (48, 1100, 1030, {}),      # chosen: no aligned divisor, padded
+])
+@pytest.mark.parametrize("antithetic", [False, True])
+def test_zo_dual_matmul_bf16_matches_float64(m, k, n, bs, antithetic):
+    """bf16 operands through the limb feed: each stream is the float64
+    product of the same bf16 values with ``w + mu*U``, within the bf16
+    rounding of the output (half an ulp) and the f32 accumulation."""
+    xa = jax.random.normal(jax.random.PRNGKey(3), (m, k), jnp.bfloat16)
+    xb = jax.random.normal(jax.random.PRNGKey(4), (m, k), jnp.bfloat16)
+    w = (0.05 * jax.random.normal(jax.random.PRNGKey(5), (k, n))).astype(
+        jnp.bfloat16)
+    mu_a, mu_b = (1e-3, -1e-3) if antithetic else (0.0, 1e-3)
+    ys = ops.zo_dual_matmul(xa, xb, w, 19, mu_a, mu_b, impl="interpret",
+                            perturb_a=antithetic, **bs)
+    for y, (r, acc) in zip(ys, _float64_dual(xa, xb, w, 19, mu_a, mu_b,
+                                             antithetic)):
+        assert y.dtype == jnp.bfloat16
+        err = np.abs(np.asarray(y, np.float64) - r)
+        assert np.all(err <= 2.0 ** -8 * np.abs(r) + acc), err.max()
+
+
+def test_limb_feed_is_exact():
+    """The three bf16 limbs sum to the f32 ``w + mu*U`` exactly, and a
+    tile's product through them loses nothing: an identity ``x`` gives
+    back ``w + mu*U`` bit for bit (and ``w`` itself on the clean
+    stream), which an f32 weight rounded to bf16, or a limb left out,
+    would not."""
+    from repro.kernels import zo_matmul as ZM
+    k, n = 64, 128
+    w = (0.05 * jax.random.normal(jax.random.PRNGKey(7), (k, n))).astype(
+        jnp.bfloat16)
+    u = ZM.uniform_noise(23, (k, n))
+    wp = w.astype(jnp.float32) + 1e-3 * u
+    limbs = ZM._limbs(wp)
+    assert all(l.dtype == jnp.bfloat16 for l in limbs)
+    np.testing.assert_array_equal(
+        sum(np.asarray(l, np.float64) for l in limbs), np.asarray(wp))
+    eye = jnp.eye(k, dtype=jnp.bfloat16)
+    np.testing.assert_array_equal(
+        np.asarray(ZM._tile_product(eye, w, 1e-3, u)), np.asarray(wp))
+    np.testing.assert_array_equal(
+        np.asarray(ZM._tile_product(eye, w, 1e-3, None)),
+        np.asarray(w, np.float32))
 
 
 def test_zo_dual_matmul_antithetic_pair():
@@ -181,3 +253,30 @@ def test_row_offset_addresses_global_rows():
         u_r = ZM.uniform_noise(13, (K, N), row_offset=r * K)
         np.testing.assert_array_equal(np.asarray(u_r),
                                       np.asarray(stacked[r * K:(r + 1) * K]))
+
+
+# the fed cells' per-client dense shapes (M = 4096 tokens per client) and
+# ResNet-18's im2col convs at CIFAR size (M = 8 images x H x W)
+_CELL_SHAPES = [(4096, 1024, 1024), (4096, 1024, 4096), (4096, 4096, 1024),
+                (4096, 768, 768), (4096, 768, 3072), (4096, 3072, 768)]
+_RESNET_SHAPES = [(8192, 27, 64), (8192, 576, 64), (2048, 576, 128),
+                  (2048, 1152, 128), (2048, 64, 128), (512, 1152, 256),
+                  (512, 2304, 256), (128, 2304, 512), (128, 4608, 512),
+                  (128, 256, 512)]
+
+
+@pytest.mark.parametrize("m,k,n", _CELL_SHAPES + _RESNET_SHAPES)
+@pytest.mark.parametrize("itemsize", [2, 4])
+def test_choose_blocks(m, k, n, itemsize):
+    """Lane blocks are 128-aligned or whole axes, row blocks take at least
+    512 rows where M has them, and the kernel's VMEM estimate stays under
+    the limit handed to Mosaic.  The cells' shapes need no padding."""
+    from repro.kernels import zo_matmul as ZM
+    bm, bk, bn = ZM.choose_blocks(m, k, n, itemsize)
+    for b, dim in ((bk, k), (bn, n)):
+        assert b == dim or (b % 128 == 0 and 256 <= b <= 512)
+    assert bm == m or bm % 16 == 0
+    assert bm >= min(m, 512)
+    assert ZM.vmem_bytes(bm, bk, bn, itemsize) <= ZM.VMEM_LIMIT
+    if (m, k, n) in _CELL_SHAPES:
+        assert m % bm == 0 and k % bk == 0 and n % bn == 0

@@ -67,16 +67,23 @@ def test_zo_dual_matmul_with_noise_compiles(one_chip):
                                      _sds(one_chip, (DM, FF)))
 
 
-def test_zo_dual_matmul_vmapped_over_clients_compiles(one_chip):
+@pytest.mark.parametrize("k,n", [(DM, DM), (DM, FF), (FF, DM),      # Medium
+                                 (768, 768), (768, 3072), (3072, 768)])
+def test_zo_dual_matmul_vmapped_over_clients_compiles(one_chip, k, n):
     """The federated round vmaps the client forward over the cohort, so
-    each client's seed reaches the kernel batched."""
-    def one(x, w, seed):
-        return ZM.zo_dual_matmul(x, x, w, seed, 0.0, 1e-3,
-                                 interpret=False)[1]
+    each client's seed reaches the kernel batched.  At every dense shape
+    of the two fed cells (4096 tokens per client) the chosen blocks
+    compile within the kernel's VMEM limit, with no padding round the
+    call."""
+    def one(xa, xb, w, seed):
+        return ZM.zo_dual_matmul(xa, xb, w, seed, 0.0, 1e-3,
+                                 interpret=False)
 
-    assert "tpu_custom_call" in _hlo(
-        jax.vmap(one), _sds(one_chip, (4, 2 * S, DM)),
-        _sds(one_chip, (4, DM, FF)), _sds(one_chip, (4,), jnp.int32))
+    x = _sds(one_chip, (4, M, k))
+    text = _hlo(jax.vmap(one), x, x, _sds(one_chip, (4, k, n)),
+                _sds(one_chip, (4,), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert " pad(" not in text
 
 
 @pytest.mark.parametrize("seq", [S, 1001])
