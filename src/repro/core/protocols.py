@@ -43,8 +43,10 @@ class ModelAPI:
     server_loss: Callable   # (server_params, client_const, smashed, batch) -> loss
     joint_loss: Callable    # (client_params, server_params, batch) -> loss
     # kernel-backed fused dual probe (forward_impl="kernel"):
-    # (client_params, batch, seeds_tree, mu) -> (l_clean, l_pert, smashed)
-    # — both ZO losses of one pair from a single dual-batch forward.
+    # (client_params, batch, seeds_tree, mu) -> (l_clean, l_pert, smashed,
+    # stats) — both ZO losses of one pair from a single dual-batch
+    # forward; ``stats`` counts its work ({"moe_rows": ...} with a
+    # dropless MoE, else {}).
     client_dual_loss: Callable | None = None
     # leaf-seed predicate the kernel estimator AND the server replay must
     # share (attn_probe="scores" excludes attention wk/wv — the probe
@@ -74,8 +76,8 @@ def lm_api(cfg: ModelConfig, rules: AxisRules) -> ModelAPI:
 
     def aux_loss(cp, smashed, batch):
         with jax.named_scope("heron_aux_head"):
-            logits = T.aux_forward(cp, cfg, rules, smashed,
-                                   batch.get("positions"))
+            logits, _ = T.aux_forward(cp, cfg, rules, smashed,
+                                      batch.get("positions"))
             lbl = batch.get("aux_labels", batch["labels"])
             return T.lm_loss(logits, lbl, cfg.vocab)
 
@@ -103,16 +105,20 @@ def lm_api(cfg: ModelConfig, rules: AxisRules) -> ModelAPI:
         def client_dual_loss(cp, batch, seeds, mu):
             pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl=impl)
             pos = batch.get("positions")
-            s2, _ = T.client_forward(cp, cfg, rules, batch["inputs"], pos,
-                                     perturb=pz)
+            s2, ncs = T.client_forward(cp, cfg, rules, batch["inputs"],
+                                       pos, perturb=pz)
             pos2 = None if pos is None else jnp.concatenate([pos, pos], 0)
             B = batch["inputs"].shape[0]
             with jax.named_scope("heron_aux_head"):
-                logits2 = T.aux_forward(cp, cfg, rules, s2, pos2, perturb=pz)
+                logits2, aux_ncs = T.aux_forward(cp, cfg, rules, s2, pos2,
+                                                 perturb=pz)
                 lbl = batch.get("aux_labels", batch["labels"])
                 l0 = T.lm_loss(logits2[:B], lbl, cfg.vocab)
                 lp = T.lm_loss(logits2[B:], lbl, cfg.vocab)
-            return l0, lp, s2[:B]
+            stats = {}
+            if cfg.moe is not None and cfg.moe.capacity_factor is None:
+                stats["moe_rows"] = T.moe_rows((ncs, aux_ncs))
+            return l0, lp, s2[:B], stats
 
     seed_pred = None
     if impl is not None and getattr(cfg, "attn_probe", "weights") == \
@@ -151,7 +157,7 @@ def cnn_api(cfg: CNN.CNNConfig) -> ModelAPI:
                 logits2 = CNN.aux_logits(cp, s2, cfg, pz)
                 l0 = CNN.xent(logits2[:B], batch["labels"])
                 lp = CNN.xent(logits2[B:], batch["labels"])
-            return l0, lp, s2[:B]
+            return l0, lp, s2[:B], {}
 
     return ModelAPI(client_loss, aux_loss, server_loss, joint_loss,
                     client_dual_loss)
@@ -411,6 +417,8 @@ def _make_local_update(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
         def closs(cpx):
             return api.client_loss(cpx, batch)
 
+        stats = {}
+
         if method == "heron":
             if kernel_client:
                 def dloss(cpx, seeds, mu):
@@ -418,6 +426,7 @@ def _make_local_update(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
 
                 g, info = Z.zo_gradient_kernel(dloss, cp, key, zo_cfg,
                                                seed_pred=api.seed_pred)
+                stats = info["stats"]
             else:
                 g, info = Z.zo_gradient(closs, cp, key, zo_cfg)
             loss, smashed = info["loss"], info["aux"]
@@ -430,7 +439,7 @@ def _make_local_update(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
             (loss, smashed), g = jax.value_and_grad(closs, has_aux=True)(cp)
             coeffs = jnp.zeros((zo_cfg.n_pairs,))
             cp, oc = client_opt.update(g, oc, cp)
-        return cp, oc, smashed, loss, coeffs
+        return cp, oc, smashed, loss, coeffs, stats
 
     return local_update
 
@@ -446,7 +455,8 @@ def _make_cohort_trajectory(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
 
     Returns ``(run, kernel_client)`` where
     ``run(state_client, round_batch, key) ->
-    (client_keys, cps, smashed_all, losses, coeffs_all)``.
+    (client_keys, cps, smashed_all, losses, coeffs_all, stats)``, the
+    clients' dual-probe counters summed over the cohort and its steps.
     """
     kernel_client = api.client_dual_loss is not None and method == "heron"
     local_update = _make_local_update(api, method, zo_cfg, client_opt,
@@ -476,14 +486,15 @@ def _make_cohort_trajectory(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
                 else:
                     keys = jax.vmap(
                         lambda ck: jax.random.fold_in(ck, m))(client_keys)
-                cps, ocs, smashed, losses, coeffs = jax.vmap(
+                cps, ocs, smashed, losses, coeffs, stats = jax.vmap(
                     local_update, in_axes=(0, 0, 0, 0))(cps, ocs, batch_m,
                                                         keys)
-                return (cps, ocs), (smashed, losses, coeffs)
+                return (cps, ocs), (smashed, losses, coeffs, stats)
 
-            (cps, _), (smashed_all, losses, coeffs_all) = jax.lax.scan(
-                step_m, (cp0, oc0), jnp.arange(h))
-            return client_keys, cps, smashed_all, losses, coeffs_all
+            (cps, _), (smashed_all, losses, coeffs_all, stats) = \
+                jax.lax.scan(step_m, (cp0, oc0), jnp.arange(h))
+            stats = jax.tree.map(jnp.sum, stats)
+            return client_keys, cps, smashed_all, losses, coeffs_all, stats
 
     return run, kernel_client
 
@@ -588,8 +599,8 @@ def make_fed_round(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
             return _fo_locked_round(api, method, fed, client_opt,
                                     server_opt, state, round_batch, key)
 
-        client_keys, cps, smashed_all, losses, coeffs_all = run_cohort(
-            state["client"], round_batch, key)
+        client_keys, cps, smashed_all, losses, coeffs_all, stats = \
+            run_cohort(state["client"], round_batch, key)
         cp_const = jax.lax.stop_gradient(state["client"])
         sp, os_, s_losses = server_updates(
             state["server"], state["opt_server"], cp_const, round_batch,
@@ -620,7 +631,8 @@ def make_fed_round(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
                    "participants": jnp.sum(mask),
                    "uplink_bytes": jnp.asarray(lean_bytes, jnp.float32),
                    "uplink_bytes_dense": jnp.asarray(dense_bytes,
-                                                     jnp.float32)}
+                                                     jnp.float32),
+                   **stats}
         return ({"client": new_client, "server": sp, "opt_server": os_},
                 metrics)
 
@@ -678,7 +690,7 @@ def make_async_round(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
 
     def round_fn(state, round_batch, key, durations=None):
         N, h = fed.n_clients, fed.h
-        client_keys, cps, smashed_all, losses, coeffs_all = run_cohort(
+        client_keys, cps, smashed_all, losses, coeffs_all, _ = run_cohort(
             state["client"], round_batch, key)
         coeffs_nhp = jnp.transpose(coeffs_all, (1, 0, 2))
         mask = AG.straggler_mask(jax.random.fold_in(key, 777), N,

@@ -205,33 +205,37 @@ def zo_gradient_kernel(dual_loss_fn, params, base_seed, zo: ZOConfig,
                        seed_pred=None):
     """Two-point ZO gradient with the fused kernel noise stream.
 
-    ``dual_loss_fn(params, seeds_tree, mu) -> (l_clean, l_pert, aux)``
-    must evaluate BOTH losses of the pair — the model's dual-probe
-    forward does this in one pass per layer.  ``params`` may contain
-    None placeholders (frozen leaves from ``partition``); their seeds
-    are None and they are never perturbed.  Returns (grad_tree, info)
-    with the same contract as :func:`zo_gradient` (coeffs are the
-    lean-uplink scalars; see :func:`replay_gradient_kernel`).
+    ``dual_loss_fn(params, seeds_tree, mu) -> (l_clean, l_pert, aux,
+    stats)`` must evaluate BOTH losses of the pair — the model's
+    dual-probe forward does this in one pass per layer; ``stats`` is a
+    dict of counters of that forward (possibly empty).  ``params`` may
+    contain None placeholders (frozen leaves from ``partition``); their
+    seeds are None and they are never perturbed.  Returns (grad_tree,
+    info) with the same contract as :func:`zo_gradient` (coeffs are the
+    lean-uplink scalars; see :func:`replay_gradient_kernel`), plus
+    ``info["stats"]``, the counters summed over the pairs.
     """
     g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
     if zo.n_pairs == 0:
         seeds = O.leaf_seed_tree(params, base_seed, seed_pred)
-        l0, _, aux = dual_loss_fn(params, seeds, zo.mu)
-        return g0, {"loss": l0, "aux": aux, "coeffs": jnp.zeros((0,))}
+        l0, _, aux, stats = dual_loss_fn(params, seeds, zo.mu)
+        return g0, {"loss": l0, "aux": aux, "coeffs": jnp.zeros((0,)),
+                    "stats": stats}
 
     def pair_step(g, sp):
         seeds = O.leaf_seed_tree(params, sp, seed_pred)
-        l0, lp, aux = dual_loss_fn(params, seeds, zo.mu)
+        l0, lp, aux, stats = dual_loss_fn(params, seeds, zo.mu)
         coeff = (lp - l0) / zo.mu / zo.n_pairs
         u = O.kernel_direction_tree(params, seeds)
         g = jax.tree.map(lambda gl, ul: gl + coeff * ul, g, u)
-        return g, (coeff, l0, aux)
+        return g, (coeff, l0, aux, stats)
 
-    g, (coeffs, l0s, auxs) = jax.lax.scan(
+    g, (coeffs, l0s, auxs, stats) = jax.lax.scan(
         pair_step, g0, pair_seeds(base_seed, zo.n_pairs))
     info = {"loss": l0s[-1],
             "aux": jax.tree.map(lambda a: a[-1], auxs),
-            "coeffs": coeffs}
+            "coeffs": coeffs,
+            "stats": jax.tree.map(lambda a: jnp.sum(a, axis=0), stats)}
     return g, info
 
 

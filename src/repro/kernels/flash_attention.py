@@ -86,9 +86,10 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     "causal", "window", "cap", "scale", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, cap=0.0,
                     scale=None, bq=512, bk=512, interpret=True):
-    """q: (B, Sq, H, D); k, v: (B, Skv, Kv, D) -> (B, Sq, H, D)."""
+    """q, k: (B, Sq|Skv, H|Kv, D); v: (B, Skv, Kv, Dv) -> (B, Sq, H, Dv).
+    The value head dim may differ from the query/key one (MLA)."""
     B, Sq, H, D = q.shape
-    Skv, Kv = k.shape[1], k.shape[2]
+    Skv, Kv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // Kv
     scale = float(scale) if scale is not None else D ** -0.5
     (bq, Sq_p), (bk, Skv_p) = _seq_tiles(Sq, Skv, bq, bk, interpret)
@@ -112,14 +113,14 @@ def flash_attention(q, k, v, *, causal=True, window=0, cap=0.0,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, bk, D), kv_index),
-            pl.BlockSpec((1, bk, D), kv_index),
+            pl.BlockSpec((1, bk, Dv), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, D), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, Dv), lambda bh, qi, ki: (bh, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)
@@ -247,7 +248,8 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
     """Fused dual-probe flash attention: (oa, ob) in one KV pass.
 
     qa, qb: (B, Sq, H, D) clean / perturbed query streams; k, v:
-    (B, Skv, Kv, D).  Two modes:
+    (B, Skv, Kv, D) and (B, Skv, Kv, Dv), where the value head dim Dv may
+    differ from D (MLA).  Two modes:
 
     * **score probe** (``kb is None``) — both streams attend the SAME
       k/v, every K/V VMEM load is shared, and the perturbed stream adds
@@ -262,12 +264,12 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
       mask/position work, and each stream is bit-identical to a separate
       ``flash_attention`` call over its own (q, k, v).
 
-    Returns (oa, ob), each (B, Sq, H, D).
+    Returns (oa, ob), each (B, Sq, H, Dv).
     """
     B, Sq, H, D = qa.shape
     assert qb.shape == qa.shape, (qa.shape, qb.shape)
     assert (kb is None) == (vb is None)
-    Skv, Kv = k.shape[1], k.shape[2]
+    Skv, Kv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // Kv
     scale = float(scale) if scale is not None else D ** -0.5
     (bq, Sq_p), (bk, Skv_p) = _seq_tiles(Sq, Skv, bq, bk, interpret)
@@ -283,13 +285,15 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
     mu_arr = jnp.asarray([[mu_a, mu_b]], jnp.float32)
     off_arr = jnp.asarray([[row_offset]], jnp.int32)
     q_spec = pl.BlockSpec((1, bq, D), lambda bh, qi, ki: (bh, qi, 0))
-    kv_spec = pl.BlockSpec((1, bk, D), kv_index)
+    o_spec = pl.BlockSpec((1, bq, Dv), lambda bh, qi, ki: (bh, qi, 0))
+    k_spec = pl.BlockSpec((1, bk, D), kv_index)
+    v_spec = pl.BlockSpec((1, bk, Dv), kv_index)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    in_specs = [smem, smem, smem, q_spec, q_spec, kv_spec, kv_spec]
+    in_specs = [smem, smem, smem, q_spec, q_spec, k_spec, v_spec]
     args = [seed_arr, mu_arr, off_arr, _flat(qa, Sq_p), _flat(qb, Sq_p),
             _flat(k, Skv_p), _flat(v, Skv_p)]
     if not shared:
-        in_specs += [kv_spec, kv_spec]
+        in_specs += [k_spec, v_spec]
         args += [_flat(kb, Skv_p), _flat(vb, Skv_p)]
     kernel = functools.partial(
         _zo_dual_fa_kernel, nk=nk, bq=bq, bk=bk, causal=causal,
@@ -300,16 +304,16 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
         kernel,
         grid=(B * H, nq, nk),
         in_specs=in_specs,
-        out_specs=[q_spec, q_spec],
-        out_shape=[jax.ShapeDtypeStruct((B * H, Sq_p, D), qa.dtype),
-                   jax.ShapeDtypeStruct((B * H, Sq_p, D), qb.dtype)],
+        out_specs=[o_spec, o_spec],
+        out_shape=[jax.ShapeDtypeStruct((B * H, Sq_p, Dv), qa.dtype),
+                   jax.ShapeDtypeStruct((B * H, Sq_p, Dv), qb.dtype)],
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(*args)

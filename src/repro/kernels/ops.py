@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import flash_attention as FA
+from repro.kernels import grouped_matmul as GM
 from repro.kernels import ref as REF
 from repro.kernels import rg_lru as RG
 from repro.kernels import zo_matmul as ZM
@@ -88,6 +89,43 @@ def zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b, *, row_offset=0, impl=None,
     return ZM.zo_dual_matmul(xa, xb, w, seed, mu_a, mu_b,
                              row_offset=row_offset, perturb_a=perturb_a,
                              perturb_b=perturb_b, **kw)
+
+
+def zo_dual_grouped_matmul(xa, xb, w, meta, seed, mu_a, mu_b, *, bm: int,
+                           row_offset=0, expert_offset=0, impl=None,
+                           perturb_a: bool = False, perturb_b: bool = True):
+    """Both streams' rows through their held experts ``w`` (E, K, N), in
+    the tile layout ``meta`` of :func:`repro.kernels.grouped_matmul.
+    group_layout`: one read of each W tile and one noise tile for both
+    (see that module).  ``impl="xla"`` runs the jnp emulation, which
+    leaves the rows of tiles no pair tile computes at zero."""
+    impl = _resolve(impl)
+    if impl == "xla":
+        E, K, N = w.shape
+        u = uniform_noise(seed, (E * K, N),
+                          row_offset=row_offset + expert_offset * K)
+        wf = w.astype(jnp.float32)
+        u = u.reshape(wf.shape)
+
+        def one(x, tiles, valid, mu, pert):
+            n_tiles = x.shape[0] // bm
+            tile_e = jnp.full((n_tiles,), E, jnp.int32).at[
+                jnp.where(valid != 0, tiles, n_tiles)].set(meta[0],
+                                                           mode="drop")
+            row_e = jnp.repeat(tile_e, bm)[:, None]
+            ww = wf + jnp.asarray(mu, jnp.float32) * u if pert else wf
+            y = jnp.zeros((x.shape[0], N), jnp.float32)
+            for e in range(E):
+                y = jnp.where(row_e == e, x.astype(jnp.float32) @ ww[e], y)
+            return y.astype(x.dtype)
+
+        return (one(xa, meta[1], meta[3], mu_a, perturb_a),
+                one(xb, meta[2], meta[4], mu_b, perturb_b))
+    return GM.zo_dual_grouped_matmul(
+        xa, xb, w, meta, seed, mu_a, mu_b, row_offset=row_offset,
+        expert_offset=expert_offset, bm=bm,
+        interpret=impl == "interpret" or _interpret(),
+        perturb_a=perturb_a, perturb_b=perturb_b)
 
 
 def zo_dual_forward(x, w, seed, mu, *, impl=None, **kw):

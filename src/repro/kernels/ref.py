@@ -44,7 +44,7 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, cap=0.0,
     s = jnp.where(mask[None, None, None], s, -2.0e38)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, D).astype(q.dtype)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def zo_dual_flash_attention_ref(qa, qb, k, v, *, kb=None, vb=None, u=None,
@@ -84,7 +84,7 @@ def zo_dual_flash_attention_ref(qa, qb, k, v, *, kb=None, vb=None, u=None,
         s = jnp.where(mask[None, None, None], s, -2.0e38)
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bkgqs,bskd->bqkgd", p, vv.astype(jnp.float32))
-        return o.reshape(B, Sq, H, D).astype(q.dtype)
+        return o.reshape(B, Sq, H, vv.shape[-1]).astype(q.dtype)
 
     oa = one(qa, k, v, perturb_a, mu_a)
     ob = one(qb, kb if kb is not None else k,

@@ -18,6 +18,9 @@ from repro.models import layers as L
 from repro.models.config import ModelConfig
 
 NEG_INF = -2.0e38
+# f32 scores of a whole attention past which blocked_attention recomputes
+# each q block for the gradient (8192 tokens x 16 heads take 4 GiB)
+REMAT_SCORES_BYTES = 2 ** 30
 
 
 def _fa_impl(cfg) -> str | None:
@@ -83,7 +86,7 @@ def naive_attention(q, k, v, *, causal=True, window=0, cap=None, scale=None,
     s = jnp.where(m[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, D).astype(q.dtype)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -122,13 +125,19 @@ def blocked_attention(q, k, v, *, causal=True, window=0, cap=None,
                       causal_skip=False, q_offset=0, p_dtype=jnp.float32):
     """Flash-attention-style blocked attention in pure XLA.
 
+    q, k: (B, S, H|K, D); v: (B, Skv, K, Dv), Dv may differ from D (MLA).
     Never materializes the (Sq, Skv) score matrix.  With
     ``causal_skip=True`` the q-block loop is unrolled in Python and each
     q block only scans the kv blocks that are not fully masked (static
     bounds) — halves FLOPs for causal, and makes local attention O(S·W).
+    Where the whole score matrix would pass ``REMAT_SCORES_BYTES`` in f32,
+    each q block is recomputed for the gradient, so that a backward pass
+    keeps one block's scores and not all of them.
     """
     B, Sq, H, D = q.shape
     Skv, K = k.shape[1], k.shape[2]
+    Dv = v.shape[-1]
+    remat = B * H * Sq * Skv * 4 > REMAT_SCORES_BYTES
     G = H // K
     scale = scale if scale is not None else D ** -0.5
     cq = min(q_chunk, Sq)
@@ -142,7 +151,7 @@ def blocked_attention(q, k, v, *, causal=True, window=0, cap=None,
     vp = jnp.pad(v, ((0, 0), (0, Skv_p - Skv), (0, 0), (0, 0)))
     qp = qp.reshape(B, nq, cq, K, G, D)
     kp = kp.reshape(B, nk, ck, K, D)
-    vp = vp.reshape(B, nk, ck, K, D)
+    vp = vp.reshape(B, nk, ck, K, Dv)
     kv_pos_all = jnp.arange(Skv_p).reshape(nk, ck)
     # padded kv positions must never be attended: mark them far-future
     kv_valid = kv_pos_all < Skv
@@ -163,7 +172,7 @@ def blocked_attention(q, k, v, *, causal=True, window=0, cap=None,
 
         m0 = jnp.full((B, K, G, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, K, G, cq), jnp.float32)
-        a0 = jnp.zeros((B, cq, K, G, D), jnp.float32)
+        a0 = jnp.zeros((B, cq, K, G, Dv), jnp.float32)
         idxs = jnp.arange(kv_lo, kv_hi)
         (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), idxs)
         l = jnp.moveaxis(l, 3, 1)[..., None]            # (B,cq,K,G,1)
@@ -178,7 +187,8 @@ def blocked_attention(q, k, v, *, causal=True, window=0, cap=None,
             lo = 0
             if window > 0:
                 lo = max(0, (q_lo_pos - window + 1) // ck)
-            outs.append(run_q_block(qi, lo, max(hi, lo + 1)))
+            blk = functools.partial(run_q_block, qi, lo, max(hi, lo + 1))
+            outs.append(jax.checkpoint(blk)() if remat else blk())
         out = jnp.stack(outs, axis=1)                    # (B,nq,cq,K,G,D)
     else:
         # scan over q blocks with full kv range
@@ -198,16 +208,17 @@ def blocked_attention(q, k, v, *, causal=True, window=0, cap=None,
 
             m0 = jnp.full((B, K, G, cq), NEG_INF, jnp.float32)
             l0 = jnp.zeros((B, K, G, cq), jnp.float32)
-            a0 = jnp.zeros((B, cq, K, G, D), jnp.float32)
+            a0 = jnp.zeros((B, cq, K, G, Dv), jnp.float32)
             (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0),
                                           jnp.arange(nk))
             l = jnp.moveaxis(l, 3, 1)[..., None]
             return None, acc / jnp.maximum(l, 1e-30)
 
-        _, out = jax.lax.scan(q_step, None, jnp.arange(nq))
+        _, out = jax.lax.scan(jax.checkpoint(q_step) if remat else q_step,
+                              None, jnp.arange(nq))
         out = jnp.moveaxis(out, 0, 1)                    # (B,nq,cq,K,G,D)
 
-    out = out.reshape(B, Sq_p, H, D)[:, :Sq]
+    out = out.reshape(B, Sq_p, H, Dv)[:, :Sq]
     return out.astype(q.dtype)
 
 
@@ -217,7 +228,8 @@ def blocked_attention(q, k, v, *, causal=True, window=0, cap=None,
 
 def decode_attention(q, k_cache, v_cache, valid_len, *, window=0, cap=None,
                      scale=None):
-    """q: (B,1,H,D); caches: (B,S,K,D); valid_len: scalar or (B,) ints."""
+    """q: (B,1,H,D); caches: (B,S,K,D) and (B,S,K,Dv); valid_len: scalar
+    or (B,) ints."""
     B, _, H, D = q.shape
     S, K = k_cache.shape[1], k_cache.shape[2]
     G = H // K
@@ -235,7 +247,7 @@ def decode_attention(q, k_cache, v_cache, valid_len, *, window=0, cap=None,
     s = jnp.where(m[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgs,bskd->bkgd", p, v_cache.astype(jnp.float32))
-    return o.reshape(B, 1, H, D).astype(q.dtype)
+    return o.reshape(B, 1, H, v_cache.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -313,12 +325,7 @@ def attention_layer(params, x, cfg: ModelConfig, rules: AxisRules, *,
                          cfg.n_kv_heads, hd)
     else:
         k, v = cross_kv
-    if positions is None:
-        base = cache["pos"] if (cache is not None and decode) else 0
-        base = jnp.asarray(base)
-        if base.ndim == 1:        # slot-paged cache: per-request positions
-            base = base[:, None]
-        positions = base + jnp.arange(S)[None, :] * jnp.ones((B, 1), jnp.int32)
+    positions = _positions(positions, cache, decode, B, S)
     kv_positions = positions
     if score_probe and positions.ndim == 2 and positions.shape[0] == B:
         kv_positions = positions[: B // 2]      # k/v carry the clean half
@@ -342,6 +349,21 @@ def attention_layer(params, x, cfg: ModelConfig, rules: AxisRules, *,
         v = constrain(v, rules, ("batch", None, None, None))
     else:
         q = constrain(q, rules, ("batch", None, "heads", None))
+    o, new_cache = _attend(q, k, v, cfg, window=window, cache=cache,
+                           decode=decode, perturb=perturb,
+                           cross_kv=cross_kv, score_probe=score_probe)
+    o = o.reshape(B, S, cfg.n_heads * hd)
+    out = L.dense(params["wo"], o, cdt, psub(perturb, "wo"))
+    return constrain(out, rules, ("batch", None, None)), new_cache
+
+
+def _attend(q, k, v, cfg: ModelConfig, *, window: int, cache, decode: bool,
+            perturb, cross_kv=None, score_probe: bool = False):
+    """Attention of q (B, S, H, D) over k (.., D) and v (.., Dv) after
+    the projections and positions: the decode step against the cache, the
+    fused dual probe, the single-stream kernel or the blocked XLA path.
+    Returns (o (B, S, H, Dv), new_cache)."""
+    B, S = q.shape[:2]
     new_cache = None
     if decode:
         assert cache is not None and S == 1
@@ -420,8 +442,94 @@ def attention_layer(params, x, cfg: ModelConfig, rules: AxisRules, *,
             # block prefill: write the prompt's k/v so decode continues
             # at pos = S (fresh caches only — assumes cache["pos"] == 0)
             new_cache = _prefill_cache(cache, k, v)
-    o = o.reshape(B, S, cfg.n_heads * hd)
-    out = L.dense(params["wo"], o, cdt, psub(perturb, "wo"))
+    return o, new_cache
+
+
+def _positions(positions, cache, decode: bool, B: int, S: int):
+    """(B, S) absolute positions: given, or counted on from the cache."""
+    if positions is not None:
+        return positions
+    base = cache["pos"] if (cache is not None and decode) else 0
+    base = jnp.asarray(base)
+    if base.ndim == 1:        # slot-paged cache: per-request positions
+        base = base[:, None]
+    return base + jnp.arange(S)[None, :] * jnp.ones((B, 1), jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2/V3, mixer "mla")
+# ---------------------------------------------------------------------------
+
+def init_mla(pb: L.ParamBuilder, path: str, cfg: ModelConfig):
+    d, H = cfg.d_model, cfg.n_heads
+    dqk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    p = {}
+    if cfg.q_lora_rank:
+        p["wq_a"] = L.init_dense(pb, f"{path}.wq_a", d, cfg.q_lora_rank,
+                                 "d_model", None)
+        p["norm_q"] = L.init_rmsnorm(pb, f"{path}.norm_q", cfg.q_lora_rank)
+        p["wq_b"] = L.init_dense(pb, f"{path}.wq_b", cfg.q_lora_rank,
+                                 H * dqk, None, "heads")
+    else:
+        p["wq"] = L.init_dense(pb, f"{path}.wq", d, H * dqk, "d_model",
+                               "heads")
+    p["wkv_a"] = L.init_dense(pb, f"{path}.wkv_a", d,
+                              cfg.kv_lora_rank + cfg.qk_rope_dim, "d_model",
+                              None)
+    p["norm_kv"] = L.init_rmsnorm(pb, f"{path}.norm_kv", cfg.kv_lora_rank)
+    p["wkv_b"] = L.init_dense(pb, f"{path}.wkv_b", cfg.kv_lora_rank,
+                              H * (cfg.qk_nope_dim + cfg.v_head_dim), None,
+                              "heads")
+    p["wo"] = L.init_dense(pb, f"{path}.wo", H * cfg.v_head_dim, d, "heads",
+                           "d_model")
+    return p
+
+
+def mla_layer(params, x, cfg: ModelConfig, rules: AxisRules, *,
+              positions=None, cache=None, decode: bool = False,
+              perturb=None):
+    """Multi-head latent attention; returns (out, new_cache).
+
+    Per token: q = x Wq (or the low-rank x Wq_a -> RMSNorm -> Wq_b), a
+    latent c = RMSNorm(x Wkv_a[:, :r]) of rank r and one rope key k_r =
+    x Wkv_a[:, r:] shared by every head; c Wkv_b gives each head's k_nope
+    and v.  Keys are [k_nope, RoPE(k_r)], queries [q_nope, RoPE(q_rope)]:
+    query/key dim nope + rope, value dim v_head_dim, scale 1/sqrt(nope +
+    rope).  Under a dual probe every projection is a ``zo_dual_matmul``,
+    the norm scales are probed like the others, and both streams' q, k,
+    v go through one ``zo_dual_flash_attention`` (weight probe).  The
+    cache holds the decompressed k and v per head."""
+    B, S, _ = x.shape
+    H, dn, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim
+    r = cfg.kv_lora_rank
+    cdt = cfg.jnp_compute_dtype()
+    rms = functools.partial(L.rmsnorm, eps=cfg.norm_eps or 1e-6)
+    with jax.named_scope("heron_mla"):
+        if "wq" in params:
+            q = L.dense(params["wq"], x, cdt, psub(perturb, "wq"))
+        else:
+            qa = L.dense(params["wq_a"], x, cdt, psub(perturb, "wq_a"))
+            qa = L.norm_apply(rms, params["norm_q"], qa,
+                              psub(perturb, "norm_q"))
+            q = L.dense(params["wq_b"], qa, cdt, psub(perturb, "wq_b"))
+        q = _split_heads(q, H, dn + cfg.qk_rope_dim)
+        kv_a = L.dense(params["wkv_a"], x, cdt, psub(perturb, "wkv_a"))
+        c = L.norm_apply(rms, params["norm_kv"], kv_a[..., :r],
+                         psub(perturb, "norm_kv"))
+        kv = _split_heads(L.dense(params["wkv_b"], c, cdt,
+                                  psub(perturb, "wkv_b")), H, dn + dv)
+        positions = _positions(positions, cache, decode, B, S)
+        q_rope = L.apply_rope(q[..., dn:], positions, cfg.rope_theta)
+        k_rope = L.apply_rope(kv_a[..., None, r:], positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        k_rope = jnp.broadcast_to(k_rope, (B, S, H, k_rope.shape[-1]))
+        k = jnp.concatenate([kv[..., :dn], k_rope], axis=-1)
+        v = kv[..., dn:]
+        q = constrain(q, rules, ("batch", None, "heads", None))
+        o, new_cache = _attend(q, k, v, cfg, window=0, cache=cache,
+                               decode=decode, perturb=perturb)
+        o = o.reshape(B, S, H * dv)
+        out = L.dense(params["wo"], o, cdt, psub(perturb, "wo"))
     return constrain(out, rules, ("batch", None, None)), new_cache
 
 
@@ -454,10 +562,14 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq: int, *, local: bool,
     layout the fused decode engine uses so requests of different lengths
     coexist in one batch (see :mod:`repro.core.decode`)."""
     size = min(seq, cfg.window) if local and cfg.window > 0 else seq
-    hd = cfg.resolved_head_dim
+    heads, dk = cfg.n_kv_heads, cfg.resolved_head_dim
+    dv = dk
+    if cfg.kv_lora_rank:          # MLA: decompressed k and v per head
+        heads, dk = cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim
+        dv = cfg.v_head_dim
     dt = cfg.jnp_compute_dtype()
     return {
-        "k": jnp.zeros((batch, size, cfg.n_kv_heads, hd), dt),
-        "v": jnp.zeros((batch, size, cfg.n_kv_heads, hd), dt),
+        "k": jnp.zeros((batch, size, heads, dk), dt),
+        "v": jnp.zeros((batch, size, heads, dv), dt),
         "pos": jnp.zeros((batch,) if per_slot else (), jnp.int32),
     }

@@ -9,18 +9,28 @@ import jax.numpy as jnp
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
-    n_experts: int
+    n_experts: int                 # the router's outputs (all experts)
     top_k: int
     d_ff_expert: int
-    capacity_factor: float = 1.25
-    n_shared_experts: int = 0      # kimi-style shared expert
+    capacity_factor: float | None = 1.25  # None: dropless over the held
+                                          # experts (models/moe.moe_held)
+    n_shared_experts: int = 0      # shared experts, one gated MLP
     router_dtype: str = "float32"
+    scoring: str = "softmax"       # softmax | sigmoid (DeepSeek-V3)
+    routed_scale: float = 1.0      # routed_scaling_factor
+    n_held: int = 0                # experts this chip holds; 0 => all
+    expert_offset: int = 0         # global index of the first one held
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One layer = mixer + ffn."""
-    mixer: str = "global_attn"     # global_attn|local_attn|rg_lru|mlstm|slstm
+    mixer: str = "global_attn"     # global_attn|local_attn|mla|rg_lru|
+                                   # mlstm|slstm
     ffn: str = "dense"             # dense|moe|none
 
 
@@ -49,6 +59,14 @@ class ModelConfig:
     final_softcap: float | None = None
     attn_scale: float | None = None
     window: int = 4096             # local-attention window
+    # --- multi-head latent attention (mixer "mla", DeepSeek-V2/V3) ---
+    q_lora_rank: int = 0           # 0 => q = x @ wq (no low-rank path)
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    norm_eps: float | None = None  # None => each norm's own default
+    n_dense_layers: int = 0        # leading layers whose ffn is dense
     moe: MoECfg | None = None
     # --- enc-dec (seamless-m4t) ---
     enc_dec: bool = False
@@ -111,7 +129,10 @@ class ModelConfig:
 
     def layer_specs(self) -> tuple[LayerSpec, ...]:
         reps = (self.n_layers + len(self.pattern) - 1) // len(self.pattern)
-        return (self.pattern * reps)[: self.n_layers]
+        specs = (self.pattern * reps)[: self.n_layers]
+        return tuple(dataclasses.replace(s, ffn="dense")
+                     if i < self.n_dense_layers else s
+                     for i, s in enumerate(specs))
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

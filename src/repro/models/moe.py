@@ -1,6 +1,6 @@
 """Mixture-of-Experts FFN.
 
-Three execution paths sharing one routing function:
+Paths sharing one routing function:
 
 * ``moe_reference`` — computes *all* experts for all tokens and combines
   with the top-k gates.  Exact (no token dropping); the tests' oracle.
@@ -10,6 +10,12 @@ Three execution paths sharing one routing function:
   sharded (batch over data axes, sequence over the model axis), experts
   sharded over the model axis (EP), expert weights FSDP-gathered
   just-in-time, dispatch/return via ``lax.all_to_all``.
+* ``moe_held``      — dropless, over the experts this chip holds
+  (``MoECfg.n_held`` from ``expert_offset``; ``capacity_factor`` None):
+  the router scores every expert, the layer computes its held experts'
+  part for every (token, held expert) pair and adds the shared experts.
+  Its grouped products are ``lax.ragged_dot`` (which has a gradient);
+  under a dual ZO probe, ``kernels/grouped_matmul`` for both streams.
 
 Capacity semantics match GShard/Switch: per-expert capacity
 ``C = ceil(T·k·cf / E)``; overflow tokens are dropped (their residual
@@ -25,21 +31,23 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import AxisRules, constrain
+from repro.kernels import grouped_matmul as GM
+from repro.kernels import ops as O
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 
 
 def init_moe(pb: L.ParamBuilder, path: str, cfg: ModelConfig):
     m = cfg.moe
-    d = cfg.d_model
+    d, e = cfg.d_model, m.held
     p = {
         "router": pb.param(f"{path}.router", (d, m.n_experts),
                            ("d_model", "experts"), "normal", 0.02),
-        "up": pb.param(f"{path}.up", (m.n_experts, d, m.d_ff_expert),
+        "up": pb.param(f"{path}.up", (e, d, m.d_ff_expert),
                        ("experts", "d_model", "expert_ff"), "normal"),
-        "gate": pb.param(f"{path}.gate", (m.n_experts, d, m.d_ff_expert),
+        "gate": pb.param(f"{path}.gate", (e, d, m.d_ff_expert),
                          ("experts", "d_model", "expert_ff"), "normal"),
-        "down": pb.param(f"{path}.down", (m.n_experts, m.d_ff_expert, d),
+        "down": pb.param(f"{path}.down", (e, m.d_ff_expert, d),
                          ("experts", "expert_ff", "d_model"), "normal"),
     }
     if m.n_shared_experts:
@@ -49,15 +57,28 @@ def init_moe(pb: L.ParamBuilder, path: str, cfg: ModelConfig):
     return p
 
 
-def route(router_w, x_flat, cfg: ModelConfig):
-    """x_flat: (T, d) -> gates (T, k) f32, idx (T, k) i32."""
+def route(router_w, x_flat, cfg: ModelConfig, bias=None):
+    """x_flat: (T, d) -> gates (T, k) f32, idx (T, k) i32 over all experts.
+
+    ``scoring="softmax"``: the top-k of the softmax, renormalized.
+    ``"sigmoid"`` (DeepSeek-V3's noaux_tc with one group): sigmoid scores,
+    the top-k chosen on ``scores + bias`` (the selection bias, zero when
+    None), the gates the chosen experts' unbiased scores, renormalized.
+    Either way the gates are scaled by ``routed_scale``.  The logits are
+    an f32 product at full precision."""
     m = cfg.moe
-    logits = (x_flat.astype(jnp.float32)
-              @ router_w.astype(jnp.float32))              # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, m.top_k)
+    logits = jnp.dot(x_flat.astype(jnp.float32),
+                     router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)  # (T, E)
+    if m.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        pick = scores if bias is None else scores + bias
+        _, idx = jax.lax.top_k(pick, m.top_k)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
+    else:
+        gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)
     gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
-    return gates, idx
+    return gates * m.routed_scale, idx
 
 
 def _capacity(n_tokens: int, cfg: ModelConfig) -> int:
@@ -82,9 +103,10 @@ def moe_reference(params, x, cfg: ModelConfig):
     xf = x.reshape(-1, d)
     gates, idx = route(params["router"], xf, cfg)
     m = cfg.moe
-    # (T, E) combine weights
+    # (T, E) combine weights, of the held experts
     comb = jnp.zeros((xf.shape[0], m.n_experts), jnp.float32)
     comb = jax.vmap(lambda c, i, g: c.at[i].add(g))(comb, idx, gates)
+    comb = comb[:, m.expert_offset:m.expert_offset + m.held]
     up = jnp.einsum("td,edf->tef", xf.astype(cdt), params["up"].astype(cdt))
     gt = jnp.einsum("td,edf->tef", xf.astype(cdt), params["gate"].astype(cdt))
     h = jax.nn.silu(gt) * up
@@ -207,7 +229,163 @@ def moe_ep(params, x, cfg: ModelConfig, rules: AxisRules):
     return out
 
 
+# ---------------------------------------------------------------------------
+def _dispatch(local, held: int, k: int, starts, n_rows: int):
+    """Where each (token, slot) pair's row goes in a buffer of ``n_rows``
+    rows whose held expert e's group begins at ``starts[e]``, in token
+    order within a group.  ``local``: (T, k) expert index relative to the
+    first held one.  Returns (row_tok (n_rows,) the token of each row,
+    row_live (n_rows,) whether a pair fills it, dest (T, k) each pair's
+    row, n_rows for the pairs of experts not held here)."""
+    e = local.reshape(-1)
+    e = jnp.where((e >= 0) & (e < held), e, held)
+    order = jnp.argsort(e, stable=True)
+    e_sorted = e[order]
+    counts = jnp.bincount(e, length=held + 1)
+    first = jnp.cumsum(counts) - counts
+    rank = jnp.arange(e.shape[0]) - first[e_sorted]
+    starts = jnp.concatenate([starts, jnp.asarray([n_rows], starts.dtype)])
+    dest_sorted = jnp.where(e_sorted < held, starts[e_sorted] + rank, n_rows)
+    row_tok = jnp.zeros((n_rows,), jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    row_live = jnp.zeros((n_rows,), bool).at[dest_sorted].set(True,
+                                                              mode="drop")
+    dest = jnp.zeros_like(dest_sorted).at[order].set(dest_sorted)
+    return row_tok, row_live, dest.reshape(local.shape)
+
+
+def _gather_rows(xf, row_tok, row_live):
+    return jnp.where(row_live[:, None], xf[row_tok], 0).astype(xf.dtype)
+
+
+def _combine(y, dest, gates):
+    """sum over a token's slots of gate * its row's output (0 for a slot
+    whose expert is not held: ``dest`` past the buffer)."""
+    n_rows = y.shape[0]
+    rows = y[jnp.minimum(dest, n_rows - 1)].astype(jnp.float32)
+    return jnp.sum(jnp.where((dest < n_rows)[..., None],
+                             rows * gates[..., None], 0.0), axis=1)
+
+
+def _sizes(local, held: int):
+    e = local.reshape(-1)
+    return jnp.bincount(jnp.where((e >= 0) & (e < held), e, held),
+                        length=held + 1)[:held].astype(jnp.int32)
+
+
+def _held_ffn(params, xf, gates, local, cfg: ModelConfig):
+    """The held experts' part for one stream, dropless: rows grouped by
+    expert, three ragged products (differentiable)."""
+    m = cfg.moe
+    T, k, held = xf.shape[0], m.top_k, m.held
+    cdt = cfg.jnp_compute_dtype()
+    n_rows = T * min(k, held)
+    sizes = _sizes(local, held)
+    row_tok, row_live, dest = _dispatch(local, held, k,
+                                        jnp.cumsum(sizes) - sizes, n_rows)
+    xs = _gather_rows(xf.astype(cdt), row_tok, row_live)
+
+    def rd(a, w):
+        return jax.lax.ragged_dot(a, w.astype(cdt), sizes,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(cdt)
+
+    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
+    h = act(rd(xs, params["gate"])) * rd(xs, params["up"])
+    return _combine(rd(h, params["down"]), dest, gates)
+
+
+def _held_ffn_dual(params, xa, xb, ga, la, gb, lb, cfg: ModelConfig,
+                   perturb):
+    """Both streams' held-expert parts through the grouped dual-probe
+    kernel: one layout (``group_layout``) for the three projections.
+    Returns (out_a, out_b, rows computed)."""
+    m = cfg.moe
+    T, k, held = xa.shape[0], m.top_k, m.held
+    cdt = cfg.jnp_compute_dtype()
+    d, f = params["up"].shape[1:]
+    bm = GM.row_block(T, k, m.n_experts, ((d, f), (f, d)))
+    n_tiles = GM.capacity_tiles(T, k, held, bm)
+    n_rows = (n_tiles + 1) * bm
+    sa, sb = _sizes(la, held), _sizes(lb, held)
+    starts_a, starts_b, meta = GM.group_layout(
+        sa, sb, bm, n_tiles, GM.pair_tiles(T, k, held, bm))
+    tok_a, live_a, dest_a = _dispatch(la, held, k, starts_a, n_rows)
+    tok_b, live_b, dest_b = _dispatch(lb, held, k, starts_b, n_rows)
+    ya = _gather_rows(xa.astype(cdt), tok_a, live_a)
+    yb = _gather_rows(xb.astype(cdt), tok_b, live_b)
+    seeds = perturb.seeds
+
+    def proj(name, a, b):
+        w = params[name].astype(cdt)
+        s = seeds.get(name)
+        return O.zo_dual_grouped_matmul(
+            a, b, w, meta, 0 if s is None else s, 0.0, perturb.mu, bm=bm,
+            row_offset=jnp.asarray(perturb.rep, jnp.int32) * (
+                held * w.shape[1]),
+            expert_offset=m.expert_offset, impl=perturb.impl,
+            perturb_b=s is not None)
+
+    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
+    ua, ub = proj("up", ya, yb)
+    gta, gtb = proj("gate", ya, yb)
+    ya, yb = proj("down", act(gta) * ua, act(gtb) * ub)
+    rows = jnp.sum(sa) + jnp.sum(sb)
+    return _combine(ya, dest_a, ga), _combine(yb, dest_b, gb), rows
+
+
+def moe_held(params, x, cfg: ModelConfig, perturb=None):
+    """Dropless MoE over the held experts (see the module docstring):
+    ``sum_{selected e held here} gate_e FFN_e(x)`` plus the shared
+    experts, counted once.  Under a dual probe ``x`` stacks [clean;
+    perturbed] halves; each half routes with its own router weights.
+    Returns (out, rows): rows is the (token, held expert) pairs the
+    grouped kernel computed, both streams, None off the dual probe."""
+    m = cfg.moe
+    B, S, d = x.shape
+    cdt = cfg.jnp_compute_dtype()
+    if perturb is not None and not perturb.dual:
+        params = O.perturb_tree(params, perturb.seeds, perturb.mu,
+                                perturb.rep)
+        perturb = None
+    rows = None
+    if perturb is None:
+        xf = x.reshape(-1, d)
+        with jax.named_scope("heron_moe_route"):
+            gates, idx = route(params["router"], xf, cfg)
+        with jax.named_scope("heron_moe_experts"):
+            out = _held_ffn(params, xf, gates, idx - m.expert_offset, cfg)
+    else:
+        if m.expert_offset:
+            raise NotImplementedError(
+                "the seed replay regenerates a held expert leaf from its "
+                "first row; a nonzero expert_offset needs it shifted too")
+        half = B // 2
+        xa, xb = x[:half].reshape(-1, d), x[half:].reshape(-1, d)
+        sr = perturb.seeds.get("router")
+        with jax.named_scope("heron_moe_route"):
+            ga, ia = route(params["router"], xa, cfg)
+            wr = params["router"]
+            if sr is not None:
+                wr = wr.astype(jnp.float32) + jnp.asarray(
+                    perturb.mu, jnp.float32) * O.leaf_noise(sr, wr.shape,
+                                                            perturb.rep)
+            gb, ib = route(wr, xb, cfg)
+        with jax.named_scope("heron_moe_experts"):
+            oa, ob, rows = _held_ffn_dual(
+                params, xa, xb, ga, ia - m.expert_offset, gb,
+                ib - m.expert_offset, cfg, perturb)
+        out = jnp.concatenate([oa, ob], axis=0)
+    out = out.reshape(B, S, d).astype(x.dtype)
+    if "shared" in params:
+        out = out + L.mlp(params["shared"], x, cfg.activation, cdt,
+                          O.psub(perturb, "shared")).astype(x.dtype)
+    return out, rows
+
+
 def moe_ffn(params, x, cfg: ModelConfig, rules: AxisRules):
+    if cfg.moe.capacity_factor is None:
+        return moe_held(params, x, cfg)[0]
     if rules.mesh is not None and x.shape[1] > 1:
         return moe_ep(params, x, cfg, rules)
     return moe_xla(params, x, cfg, rules)

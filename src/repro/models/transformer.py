@@ -39,6 +39,8 @@ def init_block(pb: L.ParamBuilder, path: str, spec: LayerSpec,
     p: dict[str, Any] = {"norm1": norm_init(pb, f"{path}.norm1", d)}
     if spec.mixer in ATTN_MIXERS:
         p["attn"] = A.init_attention(pb, f"{path}.attn", cfg)
+    elif spec.mixer == "mla":
+        p["attn"] = A.init_mla(pb, f"{path}.attn", cfg)
     elif spec.mixer == "rg_lru":
         p["rec"] = R.init_rg_lru(pb, f"{path}.rec", cfg)
     elif spec.mixer == "mlstm":
@@ -66,17 +68,36 @@ def init_block(pb: L.ParamBuilder, path: str, spec: LayerSpec,
 
 def _norm(cfg: ModelConfig, params, x, perturb=None):
     fn = L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
+    if cfg.norm_eps is not None:
+        fn = functools.partial(fn, eps=cfg.norm_eps)
     return L.norm_apply(fn, params, x, perturb)
+
+
+def _kernel_ffn(spec: LayerSpec, cfg: ModelConfig) -> bool:
+    """Whether the block's ffn has a dual-probe kernel lowering: dense, or
+    the dropless MoE over held experts (not the capacity-dropping one)."""
+    return spec.ffn != "moe" or cfg.moe.capacity_factor is None
+
+
+def moe_rows(caches) -> jax.Array:
+    """The (token, held expert) rows the grouped dual-probe kernel
+    computed, summed over the layers of ``apply_stack``'s new caches."""
+    total = jnp.zeros((), jnp.int32)
+    for kp, v in jax.tree_util.tree_flatten_with_path(caches)[0]:
+        if getattr(kp[-1], "key", None) == "moe_rows":
+            total = total + jnp.sum(v).astype(jnp.int32)
+    return total
 
 
 def _block_fallback(params, x, spec: LayerSpec, cfg: ModelConfig,
                     rules: AxisRules, perturb, *, positions=None,
                     enc_out=None):
     """Whole-block XLA fallback for mixers without a fused kernel lowering
-    (recurrent blocks, MoE, cross-attention): materialize theta + mu*U for
-    the block's seeded params and run the unmodified block — the noise
-    stream (per-leaf hash seeds on canonical 2-D coordinates) is the same
-    one the fused path generates in-kernel, so replay stays exact."""
+    (recurrent blocks, the capacity-dropping MoE, cross-attention):
+    materialize theta + mu*U for the block's seeded params and run the
+    unmodified block — the noise stream (per-leaf hash seeds on canonical
+    2-D coordinates) is the same one the fused path generates in-kernel,
+    so replay stays exact."""
     pp = O.perturb_tree(params, perturb.seeds, perturb.mu, perturb.rep)
     if not perturb.dual:
         return apply_block(pp, x, spec, cfg, rules, positions=positions,
@@ -102,7 +123,8 @@ def apply_block(params, x, spec: LayerSpec, cfg: ModelConfig,
     if perturb is not None and not O.any_seed(perturb.seeds):
         perturb = None
     if perturb is not None and (
-            spec.mixer not in ATTN_MIXERS or spec.ffn == "moe"
+            spec.mixer not in ATTN_MIXERS + ("mla",)
+            or not _kernel_ffn(spec, cfg)
             or ("cross" in params and enc_out is not None)):
         return _block_fallback(params, x, spec, cfg, rules, perturb,
                                positions=positions, enc_out=enc_out)
@@ -113,6 +135,13 @@ def apply_block(params, x, spec: LayerSpec, cfg: ModelConfig,
         o, nc = A.attention_layer(
             params["attn"], h, cfg, rules, positions=positions,
             local=(spec.mixer == "local_attn"), cache=attn_cache,
+            decode=decode, perturb=O.psub(perturb, "attn"))
+        if nc is not None:
+            new_cache["attn"] = nc
+    elif spec.mixer == "mla":
+        o, nc = A.mla_layer(
+            params["attn"], h, cfg, rules, positions=positions,
+            cache=None if cache is None else cache.get("attn"),
             decode=decode, perturb=O.psub(perturb, "attn"))
         if nc is not None:
             new_cache["attn"] = nc
@@ -143,6 +172,11 @@ def apply_block(params, x, spec: LayerSpec, cfg: ModelConfig,
         if spec.ffn == "dense":
             o = L.mlp(params["mlp"], h, cfg.activation,
                       cfg.jnp_compute_dtype(), O.psub(perturb, "mlp"))
+        elif cfg.moe.capacity_factor is None:
+            o, rows = M.moe_held(params["moe"], h, cfg,
+                                 O.psub(perturb, "moe"))
+            if rows is not None:
+                new_cache["moe_rows"] = rows
         else:
             o = M.moe_ffn(params["moe"], h, cfg, rules)
         if cfg.post_norm:
@@ -157,7 +191,7 @@ def apply_block(params, x, spec: LayerSpec, cfg: ModelConfig,
 def init_block_cache(spec: LayerSpec, cfg: ModelConfig, batch: int,
                      seq: int, per_slot: bool = False):
     c: dict[str, Any] = {}
-    if spec.mixer in ATTN_MIXERS:
+    if spec.mixer in ATTN_MIXERS + ("mla",):
         c["attn"] = A.init_kv_cache(cfg, batch, seq,
                                     local=(spec.mixer == "local_attn"),
                                     per_slot=per_slot)
@@ -446,7 +480,8 @@ def client_forward(client_params, cfg: ModelConfig, rules: AxisRules,
 
 def aux_forward(client_params, cfg: ModelConfig, rules: AxisRules,
                 smashed, positions=None, perturb=None):
-    """Aux head on smashed data -> logits (client-local predictor).
+    """Aux head on smashed data -> (logits, the aux blocks' new caches)
+    (client-local predictor).
 
     In dual mode ``smashed`` carries [clean; perturbed] halves and the
     tied unembedding perturbs the table for the second half only (same
@@ -456,12 +491,13 @@ def aux_forward(client_params, cfg: ModelConfig, rules: AxisRules,
     aux = client_params["aux"]
     pa = O.psub(perturb, "aux")
     x = smashed
+    ncs = None
     if "layers" in aux:
         specs = tuple(cfg.layer_specs()[cfg.cut_layers:
                                         cfg.cut_layers + cfg.aux_layers])
-        x, _ = apply_stack(aux["layers"], x, cfg, rules, specs,
-                           positions=positions,
-                           perturb=O.psub(pa, "layers"))
+        x, ncs = apply_stack(aux["layers"], x, cfg, rules, specs,
+                             positions=positions,
+                             perturb=O.psub(pa, "layers"))
     x = _norm(cfg, aux["norm"], x, O.psub(pa, "norm"))
     pe = O.psub(perturb, "embed")
     st = None if pe is None else pe.seeds.get("table")
@@ -479,7 +515,7 @@ def aux_forward(client_params, cfg: ModelConfig, rules: AxisRules,
         else:
             logits = x.astype(jnp.float32) @ tp.T
     logits = constrain(logits, rules, ("batch", None, "vocab"))
-    return L.softcap(logits, cfg.final_softcap)
+    return L.softcap(logits, cfg.final_softcap), ncs
 
 
 def server_forward(params, cfg: ModelConfig, rules: AxisRules, smashed,

@@ -84,7 +84,8 @@ def test_exact_assigned_configs():
         "command-r-35b": (40, 8192, 64, 8, 22528, 256000),
         "qwen2.5-32b": (64, 5120, 40, 8, 27648, 152064),
         "gemma2-27b": (46, 4608, 32, 16, 36864, 256000),
-        "kimi-k2-1t-a32b": (61, 7168, 64, 8, 2048, 163840),
+        "kimi-k2-1t-a32b": (61, 7168, 64, 64, 18432, 163840),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
         "qwen3-moe-30b-a3b": (48, 2048, 32, 4, 768, 151936),
         "seamless-m4t-medium": (24, 1024, 16, 16, 4096, 256206),
         "xlstm-1.3b": (48, 2048, 4, 4, 0, 50304),
@@ -102,6 +103,14 @@ def test_exact_assigned_configs():
     # MoE specifics
     kimi = get_config("kimi-k2-1t-a32b")
     assert kimi.moe.n_experts == 384 and kimi.moe.top_k == 8
+    assert (kimi.q_lora_rank, kimi.kv_lora_rank) == (1536, 512)
+    assert kimi.moe.n_shared_experts == 1 and kimi.moe.scoring == "sigmoid"
+    ml = get_config("moonlight-16b-a3b")
+    assert (ml.moe.n_experts, ml.moe.top_k, ml.moe.d_ff_expert,
+            ml.moe.n_shared_experts) == (64, 6, 1408, 2)
+    assert (ml.kv_lora_rank, ml.qk_nope_dim, ml.qk_rope_dim,
+            ml.v_head_dim) == (512, 128, 64, 128)
+    assert [s.ffn for s in ml.layer_specs()[:2]] == ["dense", "moe"]
     q3 = get_config("qwen3-moe-30b-a3b")
     assert q3.moe.n_experts == 128 and q3.moe.top_k == 8
     # patterns

@@ -15,6 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 import hlo_scopes
 from repro.kernels import flash_attention as FA
+from repro.kernels import grouped_matmul as GM
 from repro.kernels import zo_matmul as ZM
 
 B, S, H, D = 4, 1024, 16, 64          # GPT-2 Medium attention
@@ -108,6 +109,51 @@ def test_zo_dual_flash_attention_compiles(one_chip, probe):
                                               mu_b=1e-3, interpret=False)
         args = (q,) * 4
     assert "tpu_custom_call" in _hlo(fn, *args)
+
+
+# Moonlight-16B-A3B's cell: 4 clients x 8192 tokens, 8 of 64 experts held
+# (6 per token) of width 2048 -> 1408, MLA heads of 192 / 128 dims
+ML_CLIENTS, ML_SEQ, ML_WIDTHS = 4, 8192, ((2048, 1408), (1408, 2048))
+
+
+@pytest.mark.parametrize("k,n", ML_WIDTHS)
+def test_zo_dual_grouped_matmul_compiles(one_chip, k, n):
+    """The grouped dual probe over the held experts at the cell's widths,
+    its worst-case buffers and grid, vmapped over the cohort as the round
+    calls it (the batched scalar prefetch makes JAX loop over clients)."""
+    bm = GM.row_block(ML_SEQ, 6, 64, ML_WIDTHS)
+    rows = (GM.capacity_tiles(ML_SEQ, 6, 8, bm) + 1) * bm
+    pairs = GM.pair_tiles(ML_SEQ, 6, 8, bm)
+
+    def one(xa, xb, w, meta, seed):
+        return GM.zo_dual_grouped_matmul(xa, xb, w, meta, seed, 0.0, 1e-3,
+                                         bm=bm, interpret=False)
+
+    c = ML_CLIENTS
+    x = _sds(one_chip, (c, rows, k))
+    text = _hlo(jax.vmap(one), x, x, _sds(one_chip, (c, 8, k, n)),
+                _sds(one_chip, (c, len(GM.META), pairs), jnp.int32),
+                _sds(one_chip, (c,), jnp.int32))
+    # one call over the cohort, named by its jitted wrapper (which the
+    # roofline reader matches), not a loop whose slices fuse into it
+    calls = [op for _, rest, op in hlo_scopes.instructions(text)
+             if 'custom_call_target="tpu_custom_call"' in rest]
+    assert len(calls) == 1 and calls[0].endswith(
+        "vmap(jit(zo_dual_grouped_matmul))/pallas_call")
+    assert "kind=kCustom" not in text and " while(" not in text
+
+
+def test_zo_dual_flash_attention_two_head_dims_compiles(one_chip):
+    """MLA's weight probe: queries and keys of 192 dims, values of 128,
+    8192 tokens, vmapped over the cohort."""
+    qk = _sds(one_chip, (ML_CLIENTS, 1, ML_SEQ, 16, 192))
+    v = _sds(one_chip, (ML_CLIENTS, 1, ML_SEQ, 16, 128))
+
+    def one(qa, qb, k, v, kb, vb):
+        return FA.zo_dual_flash_attention(qa, qb, k, v, kb, vb,
+                                          perturb_b=False, interpret=False)
+
+    assert "tpu_custom_call" in _hlo(jax.vmap(one), qk, qk, qk, v, qk, v)
 
 
 def test_gpt2_medium_fed_round_compiles(one_chip, monkeypatch):

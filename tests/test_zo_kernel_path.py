@@ -50,7 +50,8 @@ def test_cnn_dual_loss_matches_xla_at_mu0():
     api = P.cnn_api(cfg)
     batch = _cnn_batch()
     seeds = O.leaf_seed_tree(params["client"], jnp.int32(7))
-    l0, lp, s = api.client_dual_loss(params["client"], batch, seeds, 0.0)
+    l0, lp, s, _ = api.client_dual_loss(params["client"], batch, seeds,
+                                        0.0)
     lx, sx = api.client_loss(params["client"], batch)
     np.testing.assert_allclose(float(l0), float(lx), rtol=2e-5)
     np.testing.assert_allclose(float(lp), float(lx), rtol=2e-5)
@@ -66,7 +67,8 @@ def test_lm_dual_loss_matches_xla_at_mu0():
     api = P.lm_api(cfg, rules)
     batch = _lm_batch(cfg)
     seeds = O.leaf_seed_tree(params["client"], jnp.int32(7))
-    l0, lp, s = api.client_dual_loss(params["client"], batch, seeds, 0.0)
+    l0, lp, s, _ = api.client_dual_loss(params["client"], batch, seeds,
+                                        0.0)
     lx, sx = api.client_loss(params["client"], batch)
     np.testing.assert_allclose(float(l0), float(lx), rtol=2e-5)
     np.testing.assert_allclose(float(lp), float(lx), rtol=2e-5)
@@ -163,7 +165,7 @@ def test_zo_gradient_kernel_coeff_contract():
 
     def dual_loss(p, seeds, mu):
         pp = O.perturb_tree(p, seeds, mu)
-        return loss_of(p), loss_of(pp), None
+        return loss_of(p), loss_of(pp), None, {}
 
     zo = Z.ZOConfig(mu=1e-3, n_pairs=3)
     base = jnp.int32(42)
@@ -173,7 +175,7 @@ def test_zo_gradient_kernel_coeff_contract():
     acc = jnp.zeros_like(params["w"])
     for p, seed in enumerate(np.asarray(Z.pair_seeds(base, 3))):
         seeds = O.leaf_seed_tree(params, jnp.int32(seed))
-        l0, lp, _ = dual_loss(params, seeds, zo.mu)
+        l0, lp, _, _ = dual_loss(params, seeds, zo.mu)
         coeff = (lp - l0) / zo.mu / zo.n_pairs
         ulps = 4 * np.finfo(np.float32).eps * max(abs(float(l0)),
                                                   abs(float(lp)))
@@ -198,7 +200,7 @@ def test_replay_gradient_kernel_roundtrip():
         def f(q):
             return jnp.sum(q["a"] ** 2) + jnp.sum(jnp.sin(q["b"]["c"]))
 
-        return f(p), f(pp), None
+        return f(p), f(pp), None, {}
 
     base = jnp.int32(9)
     zo = Z.ZOConfig(mu=1e-3, n_pairs=2)
