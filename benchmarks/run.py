@@ -773,8 +773,9 @@ def bench_kernels():
     fused_fa = jax.jit(lambda qa, qb, k, v: ops.zo_dual_flash_attention(
         qa, qb, k, v, seed=7, mu_b=1e-3, perturb_b=True,
         impl="interpret", bq=bq, bk=bk))
+    # one head per grid step, as the fused kernel takes them
     one_fa = jax.jit(lambda q, k, v: FA.flash_attention(
-        q, k, v, bq=bq, bk=bk, interpret=True))
+        q, k, v, bq=bq, bk=bk, hb=1, interpret=True))
 
     def two_fa(qa, qb, k, v):
         return one_fa(qa, ka, va), one_fa(qb, ka, va)

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.distributed.sharding import AxisRules, constrain
 from repro.kernels import ops as O
+from repro.kernels.flash_attention import FLASH_BLOCK
 from repro.kernels.ops import psub
 from repro.models import layers as L
 from repro.models.config import ModelConfig
@@ -419,12 +420,17 @@ def _attend(q, k, v, cfg: ModelConfig, *, window: int, cache, decode: bool,
             o = naive_attention(q, k, v, causal=causal, window=window,
                                 cap=cfg.attn_softcap, scale=cfg.attn_scale)
         elif fa is not None and cache is None and not cfg.seq_sharding \
-                and perturb is not None and not dual:
-            # single-stream kernel-path forward under a ZO probe: the
-            # same flash kernel the dual probe fuses into.  Gated on
-            # ``perturb`` because Pallas calls have no JVP rule — the
-            # clean forward is differentiated by the FO baselines and
-            # the server-side update, so it stays on blocked_attention
+                and ((perturb is not None and not dual)
+                     or (fa == "pallas"
+                         and max(S, k.shape[1]) > FLASH_BLOCK)):
+            # the single-stream flash kernel, differentiable: under a
+            # single ZO probe, and on the chip every self-attention
+            # without a cache (the server's FO update, the FO baselines)
+            # longer than one tile.  Interpret mode keeps the rest on
+            # XLA, whose backward it would otherwise interpret in every
+            # CPU test that trains a server; so does a sequence of one
+            # tile, whose f32 scores are small (GPT-2 Small's server at
+            # 128 tokens ran slower on the kernel on one TPU v5e).
             o = O.flash_attention(q, k, v, causal=causal, window=window,
                                   cap=cfg.attn_softcap or 0.0,
                                   scale=cfg.attn_scale,

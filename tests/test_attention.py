@@ -52,3 +52,26 @@ def test_gqa_grouping():
     o4 = A.naive_attention(q, k4, v4, causal=True)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o4),
                                rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("seq,kernel", [(128, False), (640, True)])
+def test_chip_server_attention_takes_flash_past_one_tile(monkeypatch, seq,
+                                                         kernel):
+    """On the chip a differentiated self-attention runs the flash kernels
+    once its sequence spans more than one tile, and stays on XLA within
+    one (the traced gradient holds the Pallas calls or none)."""
+    from repro.configs.gpt2 import gpt2_tiny
+    from repro.distributed.sharding import AxisRules
+    from repro.models import layers as L
+
+    monkeypatch.setattr(A, "_fa_impl", lambda cfg: "pallas")
+    cfg = gpt2_tiny().replace(forward_impl="kernel")
+    params = A.init_attention(L.ParamBuilder(jax.random.PRNGKey(0)),
+                              "attn", cfg)
+
+    def loss(p, x):
+        return jnp.sum(A.attention_layer(p, x, cfg, AxisRules(mesh=None))[0])
+
+    x = jnp.ones((1, seq, cfg.d_model))
+    text = str(jax.make_jaxpr(jax.grad(loss))(params, x))
+    assert ("pallas_call" in text) == kernel

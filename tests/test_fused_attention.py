@@ -80,6 +80,51 @@ def test_clean_stream_bitmatches_single_flash(kw):
     np.testing.assert_array_equal(np.asarray(ob2), np.asarray(fb))
 
 
+GRAD_CASES = {
+    # 40 tokens on 16-row tiles: the last block of each axis is padded
+    "mha_d64_causal": dict(H=2, Kv=2, D=64, Dv=64, kw=dict(causal=True)),
+    "mla_dqk_ne_dv": dict(H=2, Kv=2, D=24, Dv=16, kw=dict(causal=True)),
+    "mla_checkpointed": dict(H=2, Kv=2, D=24, Dv=16, kw=dict(causal=True),
+                             remat=True),
+    "window": dict(H=2, Kv=2, D=16, Dv=16, kw=dict(causal=True, window=9)),
+    "softcap": dict(H=2, Kv=2, D=16, Dv=16, kw=dict(causal=True, cap=5.0)),
+    "gqa": dict(H=4, Kv=2, D=16, Dv=16, kw=dict(causal=True)),
+    "not_causal": dict(H=2, Kv=1, D=16, Dv=16, kw=dict(causal=False)),
+}
+
+
+@pytest.mark.parametrize("case", list(GRAD_CASES))
+def test_flash_attention_values_and_grads(case):
+    """The differentiable single-stream kernel's output and ``jax.vjp``
+    cotangents (dq; dk, dv summed over a GQA group) against the naive
+    attention in f32, in interpret mode."""
+    from repro.models.attention import naive_attention
+    c = GRAD_CASES[case]
+    B, S, kw = 1, 40, c["kw"]
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q = jax.random.normal(ks[0], (B, S, c["H"], c["D"]))
+    k = jax.random.normal(ks[1], (B, S, c["Kv"], c["D"]))
+    v = jax.random.normal(ks[2], (B, S, c["Kv"], c["Dv"]))
+    g = jax.random.normal(ks[3], (B, S, c["H"], c["Dv"]))
+
+    def flash(q, k, v):
+        return FA.flash_attention(q, k, v, bq=16, bk=16, interpret=True,
+                                  **kw)
+
+    def naive(q, k, v):
+        return naive_attention(q, k, v, causal=kw["causal"],
+                               window=kw.get("window", 0),
+                               cap=kw.get("cap"))
+
+    o, vjp = jax.vjp(jax.checkpoint(flash) if c.get("remat") else flash,
+                     q, k, v)
+    ro, rvjp = jax.vjp(naive, q, k, v)
+    for got, want in zip((o,) + vjp(g), (ro,) + rvjp(g)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+
 def test_mu0_score_probe_degenerates_to_clean():
     qa, qb, k, v, _, _ = _qkv()
     _, ob = FA.zo_dual_flash_attention(

@@ -206,3 +206,55 @@ def test_gpt2_medium_fed_round_compiles(one_chip, monkeypatch):
                            for op in kernels)
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 14 * 2 ** 30
+
+
+FLASH_CALLS = ("flash_attention_fwd", "flash_attention_dq",
+               "flash_attention_dkv")
+
+
+@pytest.mark.parametrize("layer,batch,seq", [("attention", B, S),
+                                             ("mla", 1, ML_SEQ)])
+def test_server_attention_gradient_takes_flash_kernels(one_chip,
+                                                       monkeypatch, layer,
+                                                       batch, seq):
+    """On the chip the server's differentiated self-attention runs the
+    flash kernel and its two backward kernels, at GPT-2 Medium's server
+    shape and Moonlight's MLA, so no f32 score tile reaches HBM: no f32
+    tensor of every head's (c, c) scores of a query block against a key
+    block, c = min(seq, q_chunk), which for Medium is all B·H·S² scores
+    (the XLA blocked path writes them in the forward, the remat forward
+    and the backward)."""
+    import math
+    import re
+
+    from repro.configs.gpt2 import gpt2_medium
+    from repro.configs.moonlight_16b_a3b import chip_share
+    from repro.distributed.sharding import AxisRules
+    from repro.models import attention as A
+    from repro.models import layers as L
+
+    monkeypatch.setattr(A, "_fa_impl", lambda cfg: "pallas")
+    if layer == "attention":
+        cfg, fn, init = gpt2_medium(), A.attention_layer, A.init_attention
+    else:
+        cfg, fn, init = chip_share(), A.mla_layer, A.init_mla
+    cfg = cfg.replace(forward_impl="kernel")
+    params = jax.tree.map(
+        lambda s: _sds(one_chip, s.shape, s.dtype),
+        init(L.ParamBuilder(None, "shape", jnp.bfloat16), "attn", cfg))
+
+    def loss(p, x):
+        out, _ = fn(p, x, cfg, AxisRules(mesh=None))
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _hlo(jax.grad(jax.checkpoint(loss), argnums=(0, 1)), params,
+                _sds(one_chip, (batch, seq, cfg.d_model)))
+    calls = {op for _, rest, op in hlo_scopes.instructions(text)
+             if 'custom_call_target="tpu_custom_call"' in rest}
+    for name in FLASH_CALLS:
+        assert any(f"/{name}/" in op for op in calls), (name, calls)
+    c = min(seq, cfg.q_chunk)
+    scores = [d for d in re.findall(r"\bf32\[([0-9,]+)\]", text)
+              if d.endswith(f",{c},{c}")
+              and math.prod(map(int, d.split(","))) >= batch * 16 * c * c]
+    assert scores == []
