@@ -293,8 +293,7 @@ def serve_phase(params, cfg, *, prompt_lens=SERVE_PROMPTS,
 
 def one_chip(cfg):
     noise_phase()
-    params, rep = train_phase(cfg.replace(forward_impl="kernel"),
-                              impl="pallas")
+    params, rep = train_phase(cfg, impl="pallas")
     if not rep["kernel_in_hlo"]:
         raise RuntimeError("no Pallas kernel (tpu_custom_call) in the "
                            "compiled round")
@@ -315,11 +314,11 @@ def replay_phase(cfg, *, clients=8, lr=CLIENT_LR, seed=0):
     client = T.init_lm(key, cfg)["client"]
     seeds = O.fold_seed(jnp.int32(seed + 7), jnp.arange(clients))
     coeffs = jax.random.normal(jax.random.fold_in(key, 1), (clients, 1, 1))
-    flat = jax.jit(functools.partial(AG.seed_replay_aggregate_kernel,
+    flat = jax.jit(functools.partial(AG.seed_replay_aggregate,
                                      lr=lr))(client, seeds, coeffs)
     mesh = make_replay_mesh()
     sharded = jax.jit(functools.partial(
-        AG.seed_replay_aggregate_kernel, lr=lr, shard="clients",
+        AG.seed_replay_aggregate, lr=lr, shard="clients",
         mesh=mesh))(client, seeds, coeffs)
     ulps = _ulps(sharded, flat)
     max_abs = _tree_max_abs_diff(sharded, flat)
@@ -343,16 +342,12 @@ def mesh_step_phase(cfg, *, batch=MESH_BATCH, seq=SEQ, seed=0):
     ``launch/train.py`` without ``--fed``) on a ("data", "model") mesh of
     every local chip vs the same step with no mesh.
 
-    The probe here is Gaussian at ``MESH_MU``, so the loss difference
-    it measures stands far above the bf16 rounding by which the two
-    programs' reductions differ.  The default unit-sphere probe at
-    mu=1e-3 moves each of GPT-2 Medium's ~1.6e8 client weights by
-    mu/sqrt(d) ~ 1e-7: its coefficient is rounding noise times d/mu,
-    and two correct programs disagree in the client update by far more
-    than a sharding error would.  The client updates are compared by
-    their cosine (same direction, same sign), since the bf16 rounding of
-    lr-sized updates leaves their element-wise difference near an ulp
-    either way."""
+    The probe here is at ``MESH_MU``, ten times the rounds' mu, so the
+    loss difference it measures stands far above the bf16 rounding by
+    which the two programs' reductions differ.  The client updates are
+    compared by their cosine (same direction, same sign), since the bf16
+    rounding of lr-sized updates leaves their element-wise difference
+    near an ulp either way."""
     key = jax.random.PRNGKey(seed)
     params = T.init_lm(key, cfg)
     copt = make_optimizer("zo_sgd", CLIENT_LR)
@@ -365,7 +360,7 @@ def mesh_step_phase(cfg, *, batch=MESH_BATCH, seq=SEQ, seed=0):
         rules = AxisRules(mesh=mesh, enable_fsdp=False)
         step = jax.jit(P.make_train_step(
             P.lm_api(cfg, rules), "heron",
-            Z.ZOConfig(mu=MESH_MU, scale="gaussian"), copt, sopt))
+            Z.ZOConfig(mu=MESH_MU), copt, sopt))
         st = P.init_train_state(jax.random.fold_in(key, 2), params, copt,
                                 sopt)
         return step(st, place_batch(batch_in, rules))

@@ -19,7 +19,6 @@ import argparse
 import jax
 import jax.numpy as jnp
 
-from repro.core import aggregate as AG
 from repro.core import protocols as P
 from repro.core import zo as Z
 from repro.data.synthetic import GaussianMixtureImages
@@ -27,6 +26,7 @@ from repro.distributed import fault as F
 from repro.fed import (AsyncReplayServer, FleetController, StalenessConfig,
                        candidate_costs, plan_cut)
 from repro.fed.cutplan import PROFILES
+from repro.kernels import ops as O
 from repro.models import cnn as CNN
 
 
@@ -36,11 +36,12 @@ def make_local_fn(api, ds, zo, h, client_lr, batch):
     mask) — pure so a fault-triggered retry replays exactly."""
 
     @jax.jit
-    def local_round(cp, ck, batches):
+    def local_round(cp, seed, batches):
         def step_m(cp, xs):
             m, bm = xs
-            g, info = Z.zo_gradient(lambda p: api.client_loss(p, bm),
-                                    cp, jax.random.fold_in(ck, m), zo)
+            g, info = Z.zo_gradient(
+                lambda p, seeds, mu: api.client_dual_loss(p, bm, seeds, mu),
+                cp, O.fold_seed(seed, m), zo, seed_pred=api.seed_pred)
             return Z.add_scaled(cp, g, -client_lr), \
                 (info["coeffs"], info["loss"])
 
@@ -56,9 +57,9 @@ def make_local_fn(api, ds, zo, h, client_lr, batch):
             lambda *xs: jnp.stack(xs),
             *[ds.batch(jax.random.fold_in(bk, m), batch)
               for m in range(h)])
-        coeffs, losses = local_round(global_params, ck, batches)
-        token = AG._raw_key_data(ck)     # the lean uplink: (key, coeffs)
-        return token, coeffs, 1.0
+        seed = Z.seed_from_key(ck)       # the lean uplink: (seed, coeffs)
+        coeffs, losses = local_round(global_params, seed, batches)
+        return seed, coeffs, 1.0
 
     return local_fn
 
@@ -99,9 +100,9 @@ def main():
     # --- buffered-async Fed-Server over the global client tree -------
     params = CNN.init_cnn(jax.random.PRNGKey(0), cfg)
     server = AsyncReplayServer(
-        params["client"], args.lr_client, zo,
+        params["client"], args.lr_client,
         staleness=StalenessConfig(alpha=args.staleness),
-        buffer_k=args.buffer_k)
+        buffer_k=args.buffer_k, seed_pred=api.seed_pred)
 
     local_fn = make_local_fn(api, ds, zo, h, args.lr_client, args.batch)
     ctl = FleetController(

@@ -94,12 +94,12 @@ def _apply_acc(global_params, acc):
 
 def _replay_engine(global_params, tokens, scales, make_direction,
                    shard: str = "none", mesh=None, chunk=None):
-    """Shared reconstruction engine behind both seed-replay aggregators.
+    """Reconstruction engine shared by :func:`seed_replay_aggregate` and
+    the async engine.
 
-    ``tokens`` is the flattened (client, step, pair) stream of replay
-    tokens — (M, 2) uint32 key data for the threefry path or (M,) int32
-    seeds for the kernel hash path — and ``scales`` the matching (M,)
-    fp32 coefficients (lr, participation mask and 1/|S| already folded
+    ``tokens`` is the flattened (client, step, pair) stream of (M,)
+    int32 replay seeds and ``scales`` the matching (M,) fp32
+    coefficients (lr, participation mask and 1/|S| already folded
     in, so padded entries are exact no-ops at scale 0).
     ``make_direction(token, shapes)`` regenerates one direction tree; it
     receives a static ShapeDtypeStruct tree, never parameter values, so
@@ -193,31 +193,22 @@ def _replay_engine(global_params, tokens, scales, make_direction,
         return _apply_acc(global_params, acc)
 
 
-def _raw_key_data(keys):
-    """uint32 key data from typed or raw PRNG keys (shard_map transports
-    raw uint32; typed key arrays don't pad/reshape)."""
-    try:
-        if jnp.issubdtype(keys.dtype, jax.dtypes.prng_key):
-            return jax.random.key_data(keys)
-    except TypeError:
-        pass
-    return keys
-
-
-def replay_token_stream(client_keys, client_coeffs, lr: float, weights,
-                        tot, kernel: bool = False):
+def replay_token_stream(client_seeds, client_coeffs, lr: float, weights,
+                        tot):
     """Flatten a cohort's lean uplinks into the (tokens, scales) stream
     :func:`_replay_engine` consumes.
 
-    ``client_keys``: (N,) PRNG keys (threefry path) or int32 seeds
-    (``kernel=True``); ``client_coeffs``: (N, h, n_pairs);  ``weights``:
-    (N,) fp32 per-client multipliers — the participation mask with any
-    staleness weight already folded in (a weight of exactly 1.0 or 0.0
-    is a bit-exact no-op on the scales);  ``tot``: the normalizer
-    (participant count for FedAvg semantics).
+    ``client_seeds``: (N,) int32 client seeds; ``client_coeffs``:
+    (N, h, n_pairs);  ``weights``: (N,) fp32 per-client multipliers — the
+    participation mask with any staleness weight already folded in (a
+    weight of exactly 1.0 or 0.0 is a bit-exact no-op on the scales);
+    ``tot``: the normalizer (participant count for FedAvg semantics).
+    The pair seed is ``fold_seed(fold_seed(client_seeds[i], m), p)`` —
+    ``fold_seed`` is elementwise, so all N·h·n_pairs seeds derive in two
+    vectorized mixes.
 
-    This is THE canonical flattening: both synchronous aggregators and
-    the async engine (:mod:`repro.fed.async_engine`) call it, so a
+    This is THE canonical flattening: the synchronous aggregator and the
+    async engine (:mod:`repro.fed.async_engine`) both call it, so a
     buffered flush over the same cohort in client order produces
     bit-identical tokens and scales to the one-shot synchronous path.
     """
@@ -226,29 +217,11 @@ def replay_token_stream(client_keys, client_coeffs, lr: float, weights,
     i_idx = flat // (h * n_pairs)
     m_idx = (flat // n_pairs) % h
     p_idx = flat % n_pairs
-    if kernel:
-        tokens = O.fold_seed(O.fold_seed(
-            jnp.asarray(client_keys, jnp.int32)[i_idx], m_idx), p_idx)
-    else:
-        ck = _raw_key_data(client_keys)
-        tokens = jax.vmap(lambda c, m, p: jax.random.fold_in(
-            jax.random.fold_in(c, m), p))(ck[i_idx], m_idx, p_idx)
+    tokens = O.fold_seed(O.fold_seed(
+        jnp.asarray(client_seeds, jnp.int32)[i_idx], m_idx), p_idx)
     scales = (-lr * client_coeffs.reshape(-1)
               * weights[i_idx] / tot).astype(jnp.float32)
     return tokens, scales
-
-
-def threefry_direction_builder(zo: Z.ZOConfig, shardings=None,
-                               shard: str = "none"):
-    """``make_direction`` closure for the threefry token stream (shared
-    by :func:`seed_replay_aggregate` and the async engine)."""
-    def make_direction(kp, shapes):
-        # sharding pins only apply outside shard_map (manual axes forbid
-        # with_sharding_constraint over the same mesh)
-        sh = shardings if shard == "none" else None
-        return Z.direction_like(kp, shapes, zo, sh)
-
-    return make_direction
 
 
 def kernel_direction_builder(seed_pred=None):
@@ -260,80 +233,48 @@ def kernel_direction_builder(seed_pred=None):
     return make_direction
 
 
-def seed_replay_aggregate(global_params, client_keys, client_coeffs,
-                          lr: float, zo: Z.ZOConfig, mask=None,
-                          shardings=None, shard: str = "none", mesh=None,
-                          chunk=None):
+def seed_replay_aggregate(global_params, client_seeds, client_coeffs,
+                          lr: float, mask=None, seed_pred=None,
+                          shard: str = "none", mesh=None, chunk=None):
     """Reconstruct the FedAvg'd client update from (seed, coeff) uplinks.
 
-    client_keys: (N,) PRNG keys (one per client round); client_coeffs:
+    client_seeds: (N,) int32 (one per client round); client_coeffs:
     (N, h, n_pairs) projected-gradient scalars for h local steps.  The
     aggregated update equals FedAvg of the clients' local ZO trajectories
     to first order in lr (exact when h==1), at an uplink cost of
     O(h·n_pairs) floats per client instead of O(d).
 
     The reconstruction is ONE jitted `lax.scan` over the flattened
-    (client, step, pair) axis: all N·h·n_pairs replay keys are derived
-    up front with a vmapped ``fold_in`` (key_imp = fold_in(fold_in(
-    client_keys[i], m), p) — the exact stream :func:`repro.core.zo.
-    zo_gradient` consumed on-client), each iteration regenerates one
-    direction and adds it into a single fp32 accumulator tree, and the
-    accumulator is applied to ``global_params`` once at the end.  With
-    ``shardings`` (a pytree of NamedShardings matching ``global_params``)
-    each regenerated direction is pinned to the parameter sharding, so
-    the server-side replay never replicates a full direction in HBM.
+    (client, step, pair) axis (:func:`replay_token_stream`): each
+    iteration regenerates one direction from the per-layer hash stream
+    the client's fused dual-probe forward generated in-kernel, adds it
+    into a single fp32 accumulator tree, and the accumulator is applied
+    to ``global_params`` once at the end.  Because the hash noise is
+    backend- and sharding-invariant, the server regenerates
+    bit-identical directions to what the clients' kernels applied.
+    ``seed_pred`` selects the seeded leaves, as on the client.
 
     ``shard``/``mesh``/``chunk`` select the mesh-sharded and/or chunked
     execution modes of :func:`_replay_engine` — the default
-    ``shard="none"``, ``chunk=None`` is the historical flat scan.
-    """
-    n = client_coeffs.shape[0]
-    if mask is None:
-        mask = jnp.ones((n,), jnp.float32)
-    tot = jnp.maximum(jnp.sum(mask), 1.0)
-    keys, scales = replay_token_stream(client_keys, client_coeffs, lr,
-                                       mask, tot)
-    make_direction = threefry_direction_builder(zo, shardings, shard)
-    return _replay_engine(global_params, keys, scales, make_direction,
-                          shard=shard, mesh=mesh, chunk=chunk)
-
-
-def seed_replay_aggregate_kernel(global_params, client_seeds, client_coeffs,
-                                 lr: float, mask=None, seed_pred=None,
-                                 shard: str = "none", mesh=None,
-                                 chunk=None):
-    """Seed-replay aggregation for the kernel noise stream.
-
-    Same flattened (client, step, pair) scan as
-    :func:`seed_replay_aggregate`, but the replay directions come from
-    the per-layer hash stream the client's fused dual-probe forward
-    generated in-kernel: client_seeds is an (N,) int32 vector and the
-    pair seed is ``fold_seed(fold_seed(client_seeds[i], m), p)`` —
-    ``fold_seed`` is elementwise, so all N·h·n_pairs seeds derive in two
-    vectorized mixes with no threefry dispatches at all.  Because the
-    hash noise is backend- and sharding-invariant, the server regenerates
-    bit-identical directions to what the clients' kernels applied.
-
-    ``shard``/``mesh``/``chunk``: same :func:`_replay_engine` execution
-    modes as :func:`seed_replay_aggregate`.
+    ``shard="none"``, ``chunk=None`` is the flat scan.
     """
     n = client_coeffs.shape[0]
     if mask is None:
         mask = jnp.ones((n,), jnp.float32)
     tot = jnp.maximum(jnp.sum(mask), 1.0)
     seeds, scales = replay_token_stream(client_seeds, client_coeffs, lr,
-                                        mask, tot, kernel=True)
+                                        mask, tot)
     make_direction = kernel_direction_builder(seed_pred)
     return _replay_engine(global_params, seeds, scales, make_direction,
                           shard=shard, mesh=mesh, chunk=chunk)
 
 
-def seed_replay_aggregate_reference(global_params, client_keys,
-                                    client_coeffs, lr: float,
-                                    zo: Z.ZOConfig, mask=None):
-    """Unvectorized triple-loop reference for :func:`seed_replay_aggregate`
-    (N·h·n_pairs full-tree Python dispatches — kept only as the oracle
-    for tests and the `seed_replay` benchmark)."""
+def seed_replay_aggregate_reference(global_params, client_seeds,
+                                    client_coeffs, lr: float, mask=None,
+                                    seed_pred=None):
+    """Unvectorized (client, step, pair) loop reference for
+    :func:`seed_replay_aggregate` (N·h·n_pairs full-tree Python
+    dispatches — kept only as the oracle for tests)."""
     n = client_coeffs.shape[0]
     if mask is None:
         mask = jnp.ones((n,), jnp.float32)
@@ -341,10 +282,12 @@ def seed_replay_aggregate_reference(global_params, client_keys,
     out = global_params
     for i in range(n):
         for m in range(client_coeffs.shape[1]):
-            key_im = jax.random.fold_in(client_keys[i], m)
+            seed_im = O.fold_seed(client_seeds[i], jnp.int32(m))
             for p in range(client_coeffs.shape[2]):
-                kp = jax.random.fold_in(key_im, p)
-                u = Z.direction_like(kp, global_params, zo)
+                seed = O.fold_seed(seed_im, jnp.int32(p))
+                u = O.kernel_direction_tree(
+                    global_params,
+                    O.leaf_seed_tree(global_params, seed, seed_pred))
                 scale = -lr * client_coeffs[i, m, p] * mask[i] / tot
                 out = Z.add_scaled(out, u, scale)
     return out

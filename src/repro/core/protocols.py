@@ -42,12 +42,12 @@ class ModelAPI:
     aux_loss: Callable      # (client_params, smashed, batch) -> loss
     server_loss: Callable   # (server_params, client_const, smashed, batch) -> loss
     joint_loss: Callable    # (client_params, server_params, batch) -> loss
-    # kernel-backed fused dual probe (forward_impl="kernel"):
+    # the ZO client's fused dual probe:
     # (client_params, batch, seeds_tree, mu) -> (l_clean, l_pert, smashed,
     # stats) — both ZO losses of one pair from a single dual-batch
     # forward; ``stats`` counts its work ({"moe_rows": ...} with a
     # dropless MoE, else {}).
-    client_dual_loss: Callable | None = None
+    client_dual_loss: Callable
     # leaf-seed predicate the kernel estimator AND the server replay must
     # share (attn_probe="scores" excludes attention wk/wv — the probe
     # moves to the score field, which is never replayed; see
@@ -56,16 +56,17 @@ class ModelAPI:
     seed_pred: Callable | None = None
 
 
-def forward_impl_of(cfg) -> str | None:
-    """Resolve a model config's forward_impl knob to a matmul backend
-    (None = the classic XLA/threefry path, no dual-probe kernels)."""
-    fi = getattr(cfg, "forward_impl", "xla")
+def forward_impl_of(cfg) -> str:
+    """Resolve a model config's forward_impl to the dual-probe matmul
+    backend: ``"kernel"`` picks it from the platform, ``"kernel_interpret"``
+    runs the Pallas kernels in interpret mode (tests)."""
+    fi = cfg.forward_impl
     if fi == "kernel":
         return O.default_forward_impl()
     if fi == "kernel_interpret":
         return "interpret"
-    assert fi == "xla", fi
-    return None
+    raise ValueError(f"forward_impl must be 'kernel' or "
+                     f"'kernel_interpret', got {fi!r}")
 
 
 def lm_api(cfg: ModelConfig, rules: AxisRules) -> ModelAPI:
@@ -99,30 +100,30 @@ def lm_api(cfg: ModelConfig, rules: AxisRules) -> ModelAPI:
             dec_positions=batch.get("dec_positions"))
         return T.lm_loss(logits, batch["labels"], cfg.vocab)
 
-    client_dual_loss = None
     impl = forward_impl_of(cfg)
-    if impl is not None:
-        def client_dual_loss(cp, batch, seeds, mu):
-            pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl=impl)
-            pos = batch.get("positions")
-            s2, ncs = T.client_forward(cp, cfg, rules, batch["inputs"],
-                                       pos, perturb=pz)
-            pos2 = None if pos is None else jnp.concatenate([pos, pos], 0)
-            B = batch["inputs"].shape[0]
-            with jax.named_scope("heron_aux_head"):
-                logits2, aux_ncs = T.aux_forward(cp, cfg, rules, s2, pos2,
-                                                 perturb=pz)
-                lbl = batch.get("aux_labels", batch["labels"])
-                l0 = T.lm_loss(logits2[:B], lbl, cfg.vocab)
-                lp = T.lm_loss(logits2[B:], lbl, cfg.vocab)
-            stats = {}
-            if cfg.moe is not None and cfg.moe.capacity_factor is None:
-                stats["moe_rows"] = T.moe_rows((ncs, aux_ncs))
-            return l0, lp, s2[:B], stats
+    if impl == "pallas" and rules.partitioned:
+        impl = "xla"    # the same stream, emulated: GSPMD can split it
+
+    def client_dual_loss(cp, batch, seeds, mu):
+        pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl=impl)
+        pos = batch.get("positions")
+        s2, ncs = T.client_forward(cp, cfg, rules, batch["inputs"],
+                                   pos, perturb=pz)
+        pos2 = None if pos is None else T.dual_positions(pos)
+        B = batch["inputs"].shape[0]
+        with jax.named_scope("heron_aux_head"):
+            logits2, aux_ncs = T.aux_forward(cp, cfg, rules, s2, pos2,
+                                             perturb=pz)
+            lbl = batch.get("aux_labels", batch["labels"])
+            l0 = T.lm_loss(logits2[:B], lbl, cfg.vocab)
+            lp = T.lm_loss(logits2[B:], lbl, cfg.vocab)
+        stats = {}
+        if cfg.moe is not None and cfg.moe.capacity_factor is None:
+            stats["moe_rows"] = T.moe_rows((ncs, aux_ncs))
+        return l0, lp, s2[:B], stats
 
     seed_pred = None
-    if impl is not None and getattr(cfg, "attn_probe", "weights") == \
-            "scores":
+    if cfg.attn_probe == "scores":
         seed_pred = O.attn_kv_seed_pred
     return ModelAPI(client_loss, aux_loss, server_loss, joint_loss,
                     client_dual_loss, seed_pred)
@@ -146,18 +147,17 @@ def cnn_api(cfg: CNN.CNNConfig) -> ModelAPI:
         s = CNN.client_forward(cp, batch["inputs"], cfg)
         return CNN.xent(CNN.server_logits(sp, s, cfg), batch["labels"])
 
-    client_dual_loss = None
     impl = forward_impl_of(cfg)
-    if impl is not None:
-        def client_dual_loss(cp, batch, seeds, mu):
-            pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl=impl)
-            s2 = CNN.client_forward(cp, batch["inputs"], cfg, pz)
-            B = batch["inputs"].shape[0]
-            with jax.named_scope("heron_aux_head"):
-                logits2 = CNN.aux_logits(cp, s2, cfg, pz)
-                l0 = CNN.xent(logits2[:B], batch["labels"])
-                lp = CNN.xent(logits2[B:], batch["labels"])
-            return l0, lp, s2[:B], {}
+
+    def client_dual_loss(cp, batch, seeds, mu):
+        pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl=impl)
+        s2 = CNN.client_forward(cp, batch["inputs"], cfg, pz)
+        B = batch["inputs"].shape[0]
+        with jax.named_scope("heron_aux_head"):
+            logits2 = CNN.aux_logits(cp, s2, cfg, pz)
+            l0 = CNN.xent(logits2[:B], batch["labels"])
+            lp = CNN.xent(logits2[B:], batch["labels"])
+        return l0, lp, s2[:B], {}
 
     return ModelAPI(client_loss, aux_loss, server_loss, joint_loss,
                     client_dual_loss)
@@ -182,14 +182,8 @@ def init_train_state(rng, params, client_opt: Optimizer,
 
 def make_train_step(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
                     client_opt: Optimizer, server_opt: Optimizer,
-                    tc_pred=None, ts_pred=None, align_weight: float = 1.0,
-                    client_shardings=None):
-    """Returns step(state, batch) -> (state, metrics).
-
-    ``client_shardings``: optional pytree of NamedShardings matching the
-    *trainable* client params — pins ZO perturbation generation to the
-    parameter sharding (never replicated on the production mesh).
-    """
+                    tc_pred=None, ts_pred=None, align_weight: float = 1.0):
+    """Returns step(state, batch) -> (state, metrics)."""
     assert method in METHODS, method
     tc_pred = tc_pred or (lambda p: True)
     ts_pred = ts_pred or (lambda p: True)
@@ -217,21 +211,14 @@ def make_train_step(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
 
             if method == "heron":
                 # --- the paper's technique: forward-only ZO client ---
-                if api.client_dual_loss is not None:
-                    # kernel noise stream: per-layer hash seeds, fused
-                    # dual-probe forward (both losses per weight read)
-                    base_seed = Z.seed_from_key(key)
+                # per-layer hash seeds, fused dual-probe forward (both
+                # losses per weight read)
+                def dloss(tcx, seeds, mu):
+                    return api.client_dual_loss(combine(tcx, fc), batch,
+                                                seeds, mu)
 
-                    def dloss(tcx, seeds, mu):
-                        return api.client_dual_loss(combine(tcx, fc),
-                                                    batch, seeds, mu)
-
-                    g_c, info = Z.zo_gradient_kernel(
-                        dloss, tc, base_seed, zo_cfg,
-                        seed_pred=api.seed_pred)
-                else:
-                    g_c, info = Z.zo_gradient(closs, tc, key, zo_cfg,
-                                              shardings=client_shardings)
+                g_c, info = Z.zo_gradient(dloss, tc, Z.seed_from_key(key),
+                                          zo_cfg, seed_pred=api.seed_pred)
                 c_loss, smashed = info["loss"], info["aux"]
                 metrics["zo_coeff_abs"] = jnp.mean(
                     jnp.abs(info["coeffs"]))
@@ -410,25 +397,21 @@ def seed_replay_uplink_bytes(n_clients: int, h: int, n_pairs: int) -> int:
 
 
 def _make_local_update(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
-                       client_opt: Optimizer, uplink: str,
-                       client_lr, kernel_client: bool):
+                       client_opt: Optimizer, uplink: str, client_lr):
     """One client's local step — shared by the sync and async rounds."""
-    def local_update(cp, oc, batch, key):
+    def local_update(cp, oc, batch, seed):
         def closs(cpx):
             return api.client_loss(cpx, batch)
 
         stats = {}
 
         if method == "heron":
-            if kernel_client:
-                def dloss(cpx, seeds, mu):
-                    return api.client_dual_loss(cpx, batch, seeds, mu)
+            def dloss(cpx, seeds, mu):
+                return api.client_dual_loss(cpx, batch, seeds, mu)
 
-                g, info = Z.zo_gradient_kernel(dloss, cp, key, zo_cfg,
-                                               seed_pred=api.seed_pred)
-                stats = info["stats"]
-            else:
-                g, info = Z.zo_gradient(closs, cp, key, zo_cfg)
+            g, info = Z.zo_gradient(dloss, cp, seed, zo_cfg,
+                                    seed_pred=api.seed_pred)
+            stats = info["stats"]
             loss, smashed = info["loss"], info["aux"]
             coeffs = info["coeffs"]
             if uplink == "seed_replay":
@@ -450,17 +433,18 @@ def _make_cohort_trajectory(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
     """The client side of a round: h decoupled local steps vmapped over
     the N-client cohort.  Factored out of :func:`make_fed_round` so the
     async engine (:func:`make_async_round`) reuses the *identical* jitted
-    trajectory — same key stream, same scan order — which is what makes
+    trajectory — same seed stream, same scan order — which is what makes
     the async path bit-exact against the sync one at zero staleness.
 
-    Returns ``(run, kernel_client)`` where
+    Returns ``(run, method == "heron")`` where
     ``run(state_client, round_batch, key) ->
-    (client_keys, cps, smashed_all, losses, coeffs_all, stats)``, the
+    (client_seeds, cps, smashed_all, losses, coeffs_all, stats)``, the
     clients' dual-probe counters summed over the cohort and its steps.
+    The flag is kept only because the benchmark's fed-round module
+    unpacks a pair.
     """
-    kernel_client = api.client_dual_loss is not None and method == "heron"
     local_update = _make_local_update(api, method, zo_cfg, client_opt,
-                                      uplink, client_lr, kernel_client)
+                                      uplink, client_lr)
 
     def run(state_client, round_batch, key):
         with jax.named_scope("heron_cohort"):
@@ -469,34 +453,27 @@ def _make_cohort_trajectory(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
                 lambda p: jnp.broadcast_to(p[None], (N,) + p.shape),
                 state_client)
             oc0 = jax.vmap(client_opt.init)(cp0)
-            # one base key per client; local step m folds m on top and
+            # one base seed per client; local step m folds m on top and
             # zo_gradient folds the pair index on top of that — the same
             # (client, step, pair) stream seed_replay_aggregate re-derives.
-            if kernel_client:
-                client_keys = O.fold_seed(Z.seed_from_key(key), jnp.arange(N))
-            else:
-                client_keys = Z.fold_in_range(key, N)
+            client_seeds = O.fold_seed(Z.seed_from_key(key), jnp.arange(N))
 
             def step_m(carry, m):
                 cps, ocs = carry
                 batch_m = jax.tree.map(lambda x: jnp.take(x, m, axis=1),
                                        round_batch)
-                if kernel_client:
-                    keys = O.fold_seed(client_keys, m)
-                else:
-                    keys = jax.vmap(
-                        lambda ck: jax.random.fold_in(ck, m))(client_keys)
+                seeds = O.fold_seed(client_seeds, m)
                 cps, ocs, smashed, losses, coeffs, stats = jax.vmap(
                     local_update, in_axes=(0, 0, 0, 0))(cps, ocs, batch_m,
-                                                        keys)
+                                                        seeds)
                 return (cps, ocs), (smashed, losses, coeffs, stats)
 
             (cps, _), (smashed_all, losses, coeffs_all, stats) = \
                 jax.lax.scan(step_m, (cp0, oc0), jnp.arange(h))
             stats = jax.tree.map(jnp.sum, stats)
-            return client_keys, cps, smashed_all, losses, coeffs_all, stats
+            return client_seeds, cps, smashed_all, losses, coeffs_all, stats
 
-    return run, kernel_client
+    return run, method == "heron"
 
 
 def _make_server_updates(api: ModelAPI, fed: FedConfig,
@@ -561,7 +538,7 @@ def make_fed_round(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
     * ``"dense"`` — clients upload their full local client params
       (O(d) floats each) and the Fed-Server runs masked FedAvg.
     * ``"seed_replay"`` — the paper's lean uplink (HERON only): client i
-      uploads its round PRNG key plus the (h, n_pairs) projected-gradient
+      uploads its round seed plus the (h, n_pairs) projected-gradient
       coefficients — O(h·n_pairs) floats — and the Fed-Server
       reconstructs the aggregate with the scan-vectorized
       :func:`repro.core.aggregate.seed_replay_aggregate`.  Clients step
@@ -589,7 +566,7 @@ def make_fed_round(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
         if client_lr is None:
             raise ValueError("seed_replay uplink needs client_lr: the "
                              "Fed-Server replays plain-SGD local steps")
-    run_cohort, kernel_client = _make_cohort_trajectory(
+    run_cohort, _ = _make_cohort_trajectory(
         api, method, zo_cfg, fed, client_opt, uplink, client_lr)
     server_updates = _make_server_updates(api, fed, server_opt)
 
@@ -599,7 +576,7 @@ def make_fed_round(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
             return _fo_locked_round(api, method, fed, client_opt,
                                     server_opt, state, round_batch, key)
 
-        client_keys, cps, smashed_all, losses, coeffs_all, stats = \
+        client_seeds, cps, smashed_all, losses, coeffs_all, stats = \
             run_cohort(state["client"], round_batch, key)
         cp_const = jax.lax.stop_gradient(state["client"])
         sp, os_, s_losses = server_updates(
@@ -612,16 +589,10 @@ def make_fed_round(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
         if uplink == "seed_replay":
             # (h, N, n_pairs) -> (N, h, n_pairs): the per-client message
             coeffs_nhp = jnp.transpose(coeffs_all, (1, 0, 2))
-            if kernel_client:
-                new_client = AG.seed_replay_aggregate_kernel(
-                    state["client"], client_keys, coeffs_nhp, client_lr,
-                    mask, seed_pred=api.seed_pred, shard=replay_shard,
-                    mesh=replay_mesh, chunk=replay_chunk)
-            else:
-                new_client = AG.seed_replay_aggregate(
-                    state["client"], client_keys, coeffs_nhp, client_lr,
-                    zo_cfg, mask, shard=replay_shard, mesh=replay_mesh,
-                    chunk=replay_chunk)
+            new_client = AG.seed_replay_aggregate(
+                state["client"], client_seeds, coeffs_nhp, client_lr,
+                mask, seed_pred=api.seed_pred, shard=replay_shard,
+                mesh=replay_mesh, chunk=replay_chunk)
             lean_bytes = seed_replay_uplink_bytes(N, h, zo_cfg.n_pairs)
         else:
             new_client = AG.fedavg_masked(cps, mask, state["client"])
@@ -684,13 +655,13 @@ def make_async_round(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
     if client_lr is None:
         raise ValueError("async round needs client_lr: the Fed-Server "
                          "replays plain-SGD local steps")
-    run_cohort, kernel_client = _make_cohort_trajectory(
+    run_cohort, _ = _make_cohort_trajectory(
         api, method, zo_cfg, fed, client_opt, "seed_replay", client_lr)
     server_updates = _make_server_updates(api, fed, server_opt)
 
     def round_fn(state, round_batch, key, durations=None):
         N, h = fed.n_clients, fed.h
-        client_keys, cps, smashed_all, losses, coeffs_all, _ = run_cohort(
+        client_seeds, cps, smashed_all, losses, coeffs_all, _ = run_cohort(
             state["client"], round_batch, key)
         coeffs_nhp = jnp.transpose(coeffs_all, (1, 0, 2))
         mask = AG.straggler_mask(jax.random.fold_in(key, 777), N,
@@ -712,18 +683,17 @@ def make_async_round(api: ModelAPI, method: str, zo_cfg: Z.ZOConfig,
             s_losses.extend(sls)
 
         srv = AsyncReplayServer(
-            state["client"], client_lr, zo_cfg, kernel=kernel_client,
+            state["client"], client_lr,
             staleness=StalenessConfig(alpha=staleness_alpha),
             buffer_k=buffer_k, shard=replay_shard, mesh=replay_mesh,
             chunk=replay_chunk, seed_pred=api.seed_pred,
             on_flush=on_flush)
 
-        tokens_host = np.asarray(client_keys) if kernel_client \
-            else np.asarray(AG._raw_key_data(client_keys))
+        seeds_host = np.asarray(client_seeds)
         mask_host = np.asarray(mask)
         for cid in order:
             cid = int(cid)
-            srv.submit(cid, tokens_host[cid], coeffs_nhp[cid],
+            srv.submit(cid, seeds_host[cid], coeffs_nhp[cid],
                        base_version=0, mask=float(mask_host[cid]),
                        t_done=float(durations[cid]))
         srv.flush()

@@ -59,6 +59,12 @@ class AxisRules:
     # if False, "fsdp" rules resolve to replication (small models)
     enable_fsdp: bool = True
 
+    @property
+    def partitioned(self) -> bool:
+        """Whether GSPMD splits the program over several devices (it
+        cannot partition a Mosaic kernel, so Pallas calls stay off)."""
+        return self.mesh is not None and self.mesh.size > 1
+
     def with_updates(self, **updates: tuple[str, ...]) -> "AxisRules":
         new = dict(self.rules)
         new.update(updates)

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import aggregate as AG
-from repro.core import zo as Z
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,18 +73,15 @@ class AsyncTelemetry:
 _APPLY_CACHE: dict = {}
 
 
-def _cached_apply(client_lr, kernel, zo, shard, mesh, seed_pred):
-    key = (client_lr, kernel, zo, shard, mesh, seed_pred)
+def _cached_apply(client_lr, shard, mesh, seed_pred):
+    key = (client_lr, shard, mesh, seed_pred)
     fn = _APPLY_CACHE.get(key)
     if fn is None:
-        if kernel:
-            md = AG.kernel_direction_builder(seed_pred)
-        else:
-            md = AG.threefry_direction_builder(zo, None, shard)
+        md = AG.kernel_direction_builder(seed_pred)
 
         def _apply(params, tokens, coeffs, weights, tot):
             toks, scales = AG.replay_token_stream(
-                tokens, coeffs, client_lr, weights, tot, kernel=kernel)
+                tokens, coeffs, client_lr, weights, tot)
             return AG._replay_engine(params, toks, scales, md,
                                      shard=shard, mesh=mesh, chunk=None)
 
@@ -96,7 +92,7 @@ def _cached_apply(client_lr, kernel, zo, shard, mesh, seed_pred):
 @dataclasses.dataclass
 class _Arrival:
     cid: int
-    token: Any              # (2,) uint32 raw key data, or int32 scalar seed
+    token: Any              # int32 scalar seed
     coeffs: Any             # (h, n_pairs)
     mask: float
     base_version: int
@@ -110,50 +106,39 @@ class AsyncReplayServer:
     ----------
     global_params: the Fed-Server's client-side global tree.
     client_lr: the replayed plain-SGD local learning rate.
-    zo: :class:`repro.core.zo.ZOConfig` for the threefry direction
-        stream; ``kernel=True`` switches to the int32 hash-seed stream
-        (then ``zo`` is unused and ``seed_pred`` selects seeded leaves).
     buffer_k: snapshot a new global every ``buffer_k`` buffered
         arrivals; ``0`` means no auto-flush — callers flush explicitly
         (the synchronous barrier limit).
     shard / mesh / chunk: forwarded to ``_replay_engine`` — the
         staleness weights live in the scales, so every execution mode
         composes unchanged.
+    seed_pred: selects the seeded leaves, as on the clients.
     on_flush: optional callback ``on_flush(cids, t)`` fired after each
         snapshot with the flushed client ids (in client-id order) and
         the flush's simulated completion time.
     """
 
-    def __init__(self, global_params, client_lr: float,
-                 zo: Z.ZOConfig | None = None, *, kernel: bool = False,
+    def __init__(self, global_params, client_lr: float, *,
                  staleness: StalenessConfig = StalenessConfig(),
                  buffer_k: int = 0, shard: str = "none", mesh=None,
-                 chunk=None, shardings=None, seed_pred=None,
+                 chunk=None, seed_pred=None,
                  on_flush: Callable | None = None):
-        if not kernel and zo is None:
-            raise ValueError("threefry replay needs a ZOConfig")
         self.params = global_params
         self.client_lr = client_lr
-        self.kernel = kernel
         self.staleness = staleness
         self.buffer_k = int(buffer_k)
         self._engine_kw = dict(shard=shard, mesh=mesh, chunk=chunk)
-        if kernel:
-            self._make_direction = AG.kernel_direction_builder(seed_pred)
-        else:
-            self._make_direction = AG.threefry_direction_builder(
-                zo, shardings, shard)
-        if chunk is None and shardings is None:
+        self._make_direction = AG.kernel_direction_builder(seed_pred)
+        if chunk is None:
             # jitted flush body, cached across server instances (one
             # compile per configuration and flush size)
-            self._apply = _cached_apply(float(client_lr), kernel, zo,
-                                        shard, mesh, seed_pred)
+            self._apply = _cached_apply(float(client_lr), shard, mesh,
+                                        seed_pred)
         else:
             # the donated-chunk stream manages its own buffers eagerly
             def _apply(params, tokens, coeffs, weights, tot):
                 toks, scales = AG.replay_token_stream(
-                    tokens, coeffs, self.client_lr, weights, tot,
-                    kernel=self.kernel)
+                    tokens, coeffs, self.client_lr, weights, tot)
                 return AG._replay_engine(params, toks, scales,
                                          self._make_direction,
                                          **self._engine_kw)
@@ -172,12 +157,12 @@ class AsyncReplayServer:
                mask: float = 1.0, t_done: float = 0.0) -> int:
         """Buffer one client's round token.
 
-        ``token`` is the client's replay token — raw (2,) uint32 key
-        data (threefry) or an int32 scalar seed (kernel);  ``coeffs``
-        the (h, n_pairs) projected-gradient scalars; ``base_version``
-        the global version the client trained from (defaults to the
-        current one, i.e. zero staleness); ``mask`` the participation
-        weight (0.0 = dropped/straggler: buffered but an exact no-op).
+        ``token`` is the client's replay token, an int32 scalar seed;
+        ``coeffs`` the (h, n_pairs) projected-gradient scalars;
+        ``base_version`` the global version the client trained from
+        (defaults to the current one, i.e. zero staleness); ``mask`` the
+        participation weight (0.0 = dropped/straggler: buffered but an
+        exact no-op).
         Returns the current global version.
         """
         if base_version is None:

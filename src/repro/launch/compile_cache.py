@@ -1,6 +1,5 @@
 """Placement of JAX's persistent compilation cache for the entry points
-(``launch/train.py``, ``launch/serve.py``, ``benchmarks/run.py``,
-``chip_smoke.py``).
+(``launch/train.py``, ``launch/serve.py``, ``chip_smoke.py``).
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
 module sets nothing.  Otherwise the cache goes to one fixed directory in

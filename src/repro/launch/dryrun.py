@@ -208,9 +208,7 @@ def lower_train(cfg, shape, mesh, method="heron"):
     }
     batch_sds = batch_specs_sharded(cfg, shape, rules)
     step = P.make_train_step(
-        api, method, Z.ZOConfig(mu=1e-3, n_pairs=1), copt, sopt,
-        client_shardings=jax.tree.map(lambda s: s.sharding,
-                                      params_sds["client"]))
+        api, method, Z.ZOConfig(mu=1e-3, n_pairs=1), copt, sopt)
     with mesh:
         lowered = jax.jit(step, donate_argnums=0).lower(state_sds,
                                                         batch_sds)

@@ -25,11 +25,11 @@ REMAT_SCORES_BYTES = 2 ** 30
 
 
 def _fa_impl(cfg) -> str | None:
-    """Resolve the config's forward_impl knob to a flash-ATTENTION kernel
+    """Resolve the config's forward_impl to a flash-ATTENTION kernel
     backend; None keeps the pure-XLA :func:`blocked_attention` path
     (which IS the online-softmax emulation of the kernel — the
     off-TPU "kernel" resolution for the clean stream)."""
-    fi = getattr(cfg, "forward_impl", "xla")
+    fi = cfg.forward_impl
     if fi == "kernel_interpret":
         return "interpret"
     if fi == "kernel" and jax.default_backend() == "tpu":
@@ -350,7 +350,7 @@ def attention_layer(params, x, cfg: ModelConfig, rules: AxisRules, *,
         v = constrain(v, rules, ("batch", None, None, None))
     else:
         q = constrain(q, rules, ("batch", None, "heads", None))
-    o, new_cache = _attend(q, k, v, cfg, window=window, cache=cache,
+    o, new_cache = _attend(q, k, v, cfg, rules, window=window, cache=cache,
                            decode=decode, perturb=perturb,
                            cross_kv=cross_kv, score_probe=score_probe)
     o = o.reshape(B, S, cfg.n_heads * hd)
@@ -358,8 +358,9 @@ def attention_layer(params, x, cfg: ModelConfig, rules: AxisRules, *,
     return constrain(out, rules, ("batch", None, None)), new_cache
 
 
-def _attend(q, k, v, cfg: ModelConfig, *, window: int, cache, decode: bool,
-            perturb, cross_kv=None, score_probe: bool = False):
+def _attend(q, k, v, cfg: ModelConfig, rules: AxisRules, *, window: int,
+            cache, decode: bool, perturb, cross_kv=None,
+            score_probe: bool = False):
     """Attention of q (B, S, H, D) over k (.., D) and v (.., Dv) after
     the projections and positions: the decode step against the cache, the
     fused dual probe, the single-stream kernel or the blocked XLA path.
@@ -409,7 +410,7 @@ def _attend(q, k, v, cfg: ModelConfig, *, window: int, cache, decode: bool,
             score_probe or (perturb.impl != "xla"
                             and cfg.attn_impl != "naive"
                             and not cfg.seq_sharding))
-        fa = _fa_impl(cfg)
+        fa = None if rules.partitioned else _fa_impl(cfg)
         if fused_dual:
             # ONE fused kernel pass carries both estimator streams —
             # the dual probe no longer rides a doubled attention batch
@@ -532,7 +533,7 @@ def mla_layer(params, x, cfg: ModelConfig, rules: AxisRules, *,
         k = jnp.concatenate([kv[..., :dn], k_rope], axis=-1)
         v = kv[..., dn:]
         q = constrain(q, rules, ("batch", None, "heads", None))
-        o, new_cache = _attend(q, k, v, cfg, window=0, cache=cache,
+        o, new_cache = _attend(q, k, v, cfg, rules, window=0, cache=cache,
                                decode=decode, perturb=perturb)
         o = o.reshape(B, S, H * dv)
         out = L.dense(params["wo"], o, cdt, psub(perturb, "wo"))

@@ -27,10 +27,10 @@ class CNNConfig:
     client_blocks: int = 1       # residual blocks on the client
     groups: int = 8
     param_dtype: str = "float32"
-    forward_impl: str = "xla"    # xla | kernel | kernel_interpret: route
-                                 # the ZO perturbed client forward through
-                                 # the Pallas dual-probe matmuls (convs
-                                 # lower via im2col)
+    forward_impl: str = "kernel"  # kernel | kernel_interpret: backend of
+                                  # the ZO client's dual-probe matmuls
+                                  # (convs lower via im2col); see
+                                  # ModelConfig.forward_impl
 
 
 def _conv_init(pb: ParamBuilder, path, kh, kw, cin, cout):

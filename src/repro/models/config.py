@@ -94,12 +94,14 @@ class ModelConfig:
     remat_policy: str = "nothing"  # nothing|save_gathers (keep FSDP-gathered
                                    # MoE weights across the bwd replay)
     scan_layers: bool = True
-    forward_impl: str = "xla"      # xla | kernel | kernel_interpret:
-                                   # "kernel" routes the client-side ZO
-                                   # perturbed forward through the Pallas
-                                   # dual-probe matmuls (emulated bit-
-                                   # equivalently off-TPU)
-    attn_probe: str = "weights"    # weights | scores (kernel path only):
+    forward_impl: str = "kernel"   # kernel | kernel_interpret: the
+                                   # backend of the ZO client's dual-probe
+                                   # kernels — "kernel" picks it from the
+                                   # platform (compiled on the TPU, the
+                                   # bit-equivalent jnp emulation
+                                   # elsewhere); "kernel_interpret" runs
+                                   # the Pallas kernels in interpret mode
+    attn_probe: str = "weights"    # weights | scores:
                                    # "weights" perturbs wq/wk/wv/wo and
                                    # runs both streams' own K/V through
                                    # one fused flash pass; "scores" keeps

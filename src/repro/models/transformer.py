@@ -450,6 +450,13 @@ def _embed_perturbed(client_params, cfg: ModelConfig, inputs, perturb):
     return x
 
 
+def dual_positions(positions):
+    """Positions for a dual-probe batch ([clean; perturbed] halves):
+    doubled along the batch axis, which is the one before the sequence
+    axis — (B, S), or (3, B, S) for M-RoPE."""
+    return jnp.concatenate([positions, positions], axis=positions.ndim - 2)
+
+
 def client_forward(client_params, cfg: ModelConfig, rules: AxisRules,
                    inputs, positions=None, caches=None, decode=False,
                    perturb=None):
@@ -468,7 +475,7 @@ def client_forward(client_params, cfg: ModelConfig, rules: AxisRules,
         assert caches is None and not decode
         x = _embed_perturbed(client_params, cfg, inputs, perturb)
         if perturb.dual and positions is not None:
-            positions = jnp.concatenate([positions, positions], axis=0)
+            positions = dual_positions(positions)
     seq_ax = "seq_model" if (cfg.seq_sharding and not decode) else None
     x = constrain(x, rules, ("batch", seq_ax, None))
     x, ncs = apply_stack(client_params["layers"], x, cfg, rules,

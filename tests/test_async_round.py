@@ -4,7 +4,7 @@ Pins the subsystem's load-bearing contracts:
 
 * staleness weight w(τ=0) is exactly 1.0, so a single full-cohort flush
   of :class:`AsyncReplayServer` is BIT-EXACT against the synchronous
-  :func:`seed_replay_aggregate` (threefry and kernel streams), and the
+  :func:`seed_replay_aggregate`, and the
   whole ``make_async_round`` at ``buffer_k=0`` is bit-exact against
   ``make_fed_round(uplink="seed_replay")`` — client AND server params;
 * masked/dropped clients contribute nothing regardless of arrival
@@ -28,6 +28,7 @@ from repro.core import zo as Z
 from repro.fed import (AsyncReplayServer, FleetController, StalenessConfig,
                        candidate_costs, plan_cut, staleness_weight)
 from repro.fed.cutplan import CutPlan, DeviceProfile
+from repro.kernels import ops as O
 
 
 def make_params():
@@ -59,43 +60,25 @@ def test_staleness_weight_properties():
 # bit-exactness vs the synchronous aggregator
 # ---------------------------------------------------------------------------
 
-def test_single_flush_bit_exact_threefry():
+def test_single_flush_bit_exact_kernel():
     """One full-cohort flush at w(τ)=1 == seed_replay_aggregate, byte
     for byte, regardless of the order arrivals were submitted in."""
     params = make_params()
     n, h, pairs, lr = 4, 2, 2, 1e-2
-    zo = Z.ZOConfig(mu=1e-3, n_pairs=pairs)
-    keys = Z.fold_in_range(jax.random.PRNGKey(42), n)
-    coeffs = jax.random.normal(jax.random.PRNGKey(1), (n, h, pairs))
+    seeds = O.fold_seed(jnp.int32(9), jnp.arange(n))
+    coeffs = jax.random.normal(jax.random.PRNGKey(5), (n, h, pairs))
     mask = jnp.array([1.0, 0.0, 1.0, 1.0])
-    ref = AG.seed_replay_aggregate(params, keys, coeffs, lr, zo, mask)
+    ref = AG.seed_replay_aggregate(params, seeds, coeffs, lr, mask)
 
-    srv = AsyncReplayServer(params, lr, zo)
-    raw = np.asarray(AG._raw_key_data(keys))
+    srv = AsyncReplayServer(params, lr)
+    sh = np.asarray(seeds)
     for cid in (2, 0, 3, 1):                      # scrambled arrivals
-        srv.submit(cid, raw[cid], coeffs[cid], mask=float(mask[cid]))
+        srv.submit(cid, sh[cid], coeffs[cid], mask=float(mask[cid]))
     assert srv.version == 0                       # buffer_k=0: no auto
     srv.flush()
     assert srv.version == 1
     assert_trees_equal(ref, srv.params)
     assert srv.telemetry.dropped == 1             # the masked client
-
-
-def test_single_flush_bit_exact_kernel():
-    from repro.kernels import ops as O
-
-    params = make_params()
-    n, h, pairs, lr = 3, 1, 2, 1e-2
-    seeds = O.fold_seed(jnp.int32(9), jnp.arange(n))
-    coeffs = jax.random.normal(jax.random.PRNGKey(5), (n, h, pairs))
-    ref = AG.seed_replay_aggregate_kernel(params, seeds, coeffs, lr)
-
-    srv = AsyncReplayServer(params, lr, kernel=True)
-    sh = np.asarray(seeds)
-    for cid in (1, 2, 0):
-        srv.submit(cid, sh[cid], coeffs[cid])
-    srv.flush()
-    assert_trees_equal(ref, srv.params)
 
 
 def test_async_round_bit_exact_vs_sync_at_buffer0():
@@ -185,19 +168,17 @@ def test_masked_clients_contribute_nothing_any_order():
 
     params = make_params()
     n, h, pairs, lr = 4, 1, 2, 1e-2
-    zo = Z.ZOConfig(mu=1e-3, n_pairs=pairs)
-    keys = Z.fold_in_range(jax.random.PRNGKey(0), n)
-    raw = np.asarray(AG._raw_key_data(keys))
+    seeds = np.asarray(O.fold_seed(jnp.int32(0), jnp.arange(n)))
     coeffs = np.asarray(
         jax.random.normal(jax.random.PRNGKey(1), (n, h, pairs)))
     orders = [(0, 1, 2, 3), (3, 2, 1, 0), (2, 0, 3, 1)]
 
     def run(order, mask, cfs, buffer_k):
-        srv = AsyncReplayServer(params, lr, zo,
+        srv = AsyncReplayServer(params, lr,
                                 staleness=StalenessConfig(alpha=0.7),
                                 buffer_k=buffer_k)
         for cid in order:
-            srv.submit(cid, raw[cid], cfs[cid], mask=mask[cid])
+            srv.submit(cid, seeds[cid], cfs[cid], mask=mask[cid])
         srv.flush()
         return srv.params
 
@@ -274,15 +255,14 @@ def test_cutplan_picks_deepest_feasible():
 def _tiny_fleet(injector=None, buffer_k=0, alpha=0.0):
     params = make_params()
     h, pairs, lr = 1, 2, 1e-2
-    zo = Z.ZOConfig(mu=1e-3, n_pairs=pairs)
-    srv = AsyncReplayServer(params, lr, zo, buffer_k=buffer_k,
+    srv = AsyncReplayServer(params, lr, buffer_k=buffer_k,
                             staleness=StalenessConfig(alpha=alpha))
 
     def local_fn(global_params, cid, round_idx, base_version):
         ck = jax.random.fold_in(
             jax.random.fold_in(jax.random.PRNGKey(7), round_idx), cid)
         coeffs = jax.random.normal(ck, (h, pairs))
-        return AG._raw_key_data(ck), coeffs, 1.0
+        return Z.seed_from_key(ck), coeffs, 1.0
 
     ctl = FleetController(srv, local_fn, injector=injector,
                           sleep=lambda s: None, max_retries=2)
@@ -354,8 +334,6 @@ def test_controller_staleness_across_versions():
 # ---------------------------------------------------------------------------
 
 def test_async_validation():
-    with pytest.raises(ValueError, match="ZOConfig"):
-        AsyncReplayServer(make_params(), 1e-2)     # threefry needs zo
     from repro.optim.optimizers import make_optimizer
     sopt = make_optimizer("adamw", 1e-3)
     with pytest.raises(ValueError, match="heron"):

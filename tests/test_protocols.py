@@ -12,6 +12,7 @@ from repro.core import zo as Z
 from repro.data.pipeline import round_batches
 from repro.data.synthetic import BigramLM, GaussianMixtureImages
 from repro.distributed.sharding import AxisRules
+from repro.kernels import ops as O
 from repro.models import cnn as CNN
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
@@ -107,26 +108,29 @@ def test_seed_replay_aggregation_matches_fedavg_h1():
     zo = Z.ZOConfig(mu=1e-4, n_pairs=2)
     lr = 1e-2
     N = 3
-    keys = [jax.random.fold_in(jax.random.PRNGKey(5), i) for i in range(N)]
+    seeds = O.fold_seed(jnp.int32(5), jnp.arange(N))
 
-    def loss_i(i):
+    def dual_loss_i(i):
         def f(p):
             return 0.5 * sum(jnp.sum((l - i) ** 2)
-                             for l in jax.tree.leaves(p)), None
-        return f
+                             for l in jax.tree.leaves(p))
+
+        def dual(p, leaf_seeds, mu):
+            return f(p), f(O.perturb_tree(p, leaf_seeds, mu)), None, {}
+        return dual
 
     explicit = []
     coeffs = []
     for i in range(N):
-        k = jax.random.fold_in(keys[i], 0)
-        g, info = Z.zo_gradient(loss_i(i), params, k, zo)
+        # local step 0 of client i: the seed the Fed-Server re-derives
+        s0 = O.fold_seed(seeds[i], 0)
+        g, info = Z.zo_gradient(dual_loss_i(i), params, s0, zo)
         explicit.append(Z.add_scaled(params, g, -lr))
         coeffs.append(info["coeffs"])
     fedavg = jax.tree.map(
         lambda *xs: jnp.mean(jnp.stack(xs), 0), *explicit)
     replay = AG.seed_replay_aggregate(
-        params, jnp.stack([k for k in keys]),
-        jnp.stack(coeffs)[:, None, :], lr, zo)
+        params, seeds, jnp.stack(coeffs)[:, None, :], lr)
     for a, b in zip(jax.tree.leaves(fedavg), jax.tree.leaves(replay)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-6)

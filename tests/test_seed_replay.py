@@ -1,8 +1,8 @@
 """Seed-replay lean uplink: the scan-vectorized reconstruction matches
-the loop oracle, (key, coeffs) replay reproduces the materialized ZO
-step, masked clients contribute nothing, the mesh-sharded / chunked
-engine modes match the flat scan, and the fed-round wiring's
-seed_replay mode matches the dense path (exact at h == 1)."""
+the loop oracle, masked clients contribute nothing, and the mesh-sharded
+/ chunked engine modes match the flat scan.  (The replayed ZO step is in
+test_zo.py, the fed round's lean-vs-dense match in
+test_zo_kernel_path.py.)"""
 import os
 import subprocess
 import sys
@@ -16,6 +16,7 @@ import pytest
 from repro.core import aggregate as AG
 from repro.core import protocols as P
 from repro.core import zo as Z
+from repro.kernels import ops as O
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -24,97 +25,68 @@ def make_params():
     return {"w": jnp.ones((6, 3)), "b": {"c": jnp.linspace(-1.0, 1.0, 5)}}
 
 
-def quad_loss(params):
-    loss = 0.0
-    for i, l in enumerate(jax.tree.leaves(params)):
-        loss = loss + 0.5 * jnp.sum((l - 0.1 * (i + 1)) ** 2)
-    return loss, None
+LOOP_CASES = {
+    # (params, n, h, n_pairs, lr, mask, rtol)
+    "tree-masked": (make_params, 3, 2, 2, 1e-2,
+                    jnp.array([1.0, 0.0, 1.0]), 1e-5),
+    "matrix": (lambda: {"w": jax.random.normal(jax.random.PRNGKey(0),
+                                               (6, 6))}, 3, 2, 2, 0.05,
+               None, 2e-5),
+}
 
 
-def test_scan_aggregate_matches_loop_reference():
-    params = make_params()
-    n, h, pairs = 3, 2, 2
-    zo = Z.ZOConfig(mu=1e-3, n_pairs=pairs)
-    keys = Z.fold_in_range(jax.random.PRNGKey(42), n)
+@pytest.mark.parametrize("case", list(LOOP_CASES))
+def test_scan_aggregate_matches_loop_reference(case):
+    """The flattened-scan replay equals the unvectorized (client, step,
+    pair) loop over fold_seed and the materialized directions."""
+    make, n, h, pairs, lr, mask, rtol = LOOP_CASES[case]
+    params = make()
+    seeds = O.fold_seed(jnp.int32(77), jnp.arange(n))
     coeffs = jax.random.normal(jax.random.PRNGKey(1), (n, h, pairs))
-    mask = jnp.array([1.0, 0.0, 1.0])
     fast = jax.jit(lambda c: AG.seed_replay_aggregate(
-        params, keys, c, 1e-2, zo, mask))(coeffs)
-    ref = AG.seed_replay_aggregate_reference(params, keys, coeffs, 1e-2,
-                                             zo, mask)
+        params, seeds, c, lr, mask))(coeffs)
+    ref = AG.seed_replay_aggregate_reference(params, seeds, coeffs, lr,
+                                             mask)
     for a, b in zip(jax.tree.leaves(fast), jax.tree.leaves(ref)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-
-
-def test_replay_update_reproduces_zo_sgd_step():
-    """theta - lr*g_hat == replay_update(theta, key, coeffs, lr): the
-    replay scan is the zo_gradient accumulation minus the forwards."""
-    params = make_params()
-    zo = Z.ZOConfig(mu=1e-4, n_pairs=3)
-    key = jax.random.PRNGKey(11)
-    g, info = Z.zo_gradient(quad_loss, params, key, zo)
-    lr = 1e-3
-    direct = Z.add_scaled(params, g, -lr)
-    replayed = Z.replay_update(params, key, info["coeffs"], lr, zo)
-    for a, b in zip(jax.tree.leaves(direct), jax.tree.leaves(replayed)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=0, atol=1e-7)
+                                   rtol=rtol, atol=1e-6)
 
 
 def test_masked_clients_contribute_nothing():
     params = make_params()
     n, h, pairs = 3, 1, 2
-    zo = Z.ZOConfig(mu=1e-3, n_pairs=pairs)
-    keys = Z.fold_in_range(jax.random.PRNGKey(0), n)
+    seeds = O.fold_seed(jnp.int32(0), jnp.arange(n))
     coeffs = jax.random.normal(jax.random.PRNGKey(1), (n, h, pairs))
     mask = jnp.array([1.0, 0.0, 1.0])
-    out = AG.seed_replay_aggregate(params, keys, coeffs, 1e-2, zo, mask)
+    out = AG.seed_replay_aggregate(params, seeds, coeffs, 1e-2, mask)
     # poisoning the masked-out client's coefficients changes nothing
     poisoned = coeffs.at[1].set(1e6)
-    out_p = AG.seed_replay_aggregate(params, keys, poisoned, 1e-2, zo,
-                                     mask)
+    out_p = AG.seed_replay_aggregate(params, seeds, poisoned, 1e-2, mask)
     for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(out_p)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # ... but an unmasked client's coefficients do
-    out_u = AG.seed_replay_aggregate(params, keys,
-                                     coeffs.at[0].set(1e6), 1e-2, zo,
-                                     mask)
+    out_u = AG.seed_replay_aggregate(params, seeds,
+                                     coeffs.at[0].set(1e6), 1e-2, mask)
     assert any(float(jnp.max(jnp.abs(a - b))) > 1e-3
                for a, b in zip(jax.tree.leaves(out),
                                jax.tree.leaves(out_u)))
 
 
-def test_chunked_streaming_bit_exact():
+def test_chunked_streaming_bit_exact_kernel():
     """Unsharded chunking continues the same scan carry: the donated
     chunk stream is bit-identical to the one-shot scan, for every chunk
     size including non-divisors of N*h*n_pairs."""
     params = make_params()
     n, h, pairs = 5, 2, 2
-    zo = Z.ZOConfig(mu=1e-3, n_pairs=pairs)
-    keys = Z.fold_in_range(jax.random.PRNGKey(4), n)
+    seeds = O.fold_seed(jnp.int32(9), jnp.arange(n))
     coeffs = jax.random.normal(jax.random.PRNGKey(5), (n, h, pairs))
-    one_shot = AG.seed_replay_aggregate(params, keys, coeffs, 1e-2, zo)
+    one_shot = AG.seed_replay_aggregate(params, seeds, coeffs, 1e-2)
     for chunk in (1, 3, 7, 20, 64):
-        chunked = AG.seed_replay_aggregate(params, keys, coeffs, 1e-2,
-                                           zo, chunk=chunk)
+        chunked = AG.seed_replay_aggregate(params, seeds, coeffs, 1e-2,
+                                           chunk=chunk)
         for a, b in zip(jax.tree.leaves(one_shot),
                         jax.tree.leaves(chunked)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_chunked_streaming_bit_exact_kernel():
-    params = make_params()
-    n, h, pairs = 4, 1, 2
-    from repro.kernels import ops as O
-    seeds = O.fold_seed(jnp.int32(9), jnp.arange(n))
-    coeffs = jax.random.normal(jax.random.PRNGKey(5), (n, h, pairs))
-    one_shot = AG.seed_replay_aggregate_kernel(params, seeds, coeffs,
-                                               1e-2)
-    chunked = AG.seed_replay_aggregate_kernel(params, seeds, coeffs,
-                                              1e-2, chunk=3)
-    for a, b in zip(jax.tree.leaves(one_shot), jax.tree.leaves(chunked)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_replay_mesh_validation():
@@ -125,14 +97,13 @@ def test_replay_mesh_validation():
 
 _SHARDED_PROG = """
     import jax, jax.numpy as jnp, numpy as np
-    from repro.core import aggregate as AG, zo as Z
+    from repro.core import aggregate as AG
     from repro.kernels import ops as O
 
     params = {"w": jax.random.normal(jax.random.PRNGKey(0), (12, 6)),
               "b": {"c": jnp.linspace(-1.0, 1.0, 7)}}
-    zo = Z.ZOConfig(mu=1e-3, n_pairs=2)
     n, h, pairs, lr = 7, 2, 2, 1e-2   # n not divisible by the mesh
-    keys = Z.fold_in_range(jax.random.PRNGKey(42), n)
+    seeds = O.fold_seed(jnp.int32(42), jnp.arange(n))
     coeffs = jax.random.normal(jax.random.PRNGKey(1), (n, h, pairs))
     mask = jnp.array([1., 1., 0., 1., 1., 0., 1.])
 
@@ -142,29 +113,22 @@ _SHARDED_PROG = """
                                        rtol=1e-5, atol=tol)
 
     for m in (None, mask):
-        flat = AG.seed_replay_aggregate(params, keys, coeffs, lr, zo, m)
-        sh = AG.seed_replay_aggregate(params, keys, coeffs, lr, zo, m,
+        flat = AG.seed_replay_aggregate(params, seeds, coeffs, lr, m)
+        sh = AG.seed_replay_aggregate(params, seeds, coeffs, lr, m,
                                       shard="clients")
         leaves_close(flat, sh)
-        shch = AG.seed_replay_aggregate(params, keys, coeffs, lr, zo, m,
+        shch = AG.seed_replay_aggregate(params, seeds, coeffs, lr, m,
                                         shard="clients", chunk=3)
         leaves_close(flat, shch)
 
     # masked clients contribute nothing under sharding: poisoning their
     # coefficients leaves the sharded result unchanged
-    sh = AG.seed_replay_aggregate(params, keys, coeffs, lr, zo, mask,
+    sh = AG.seed_replay_aggregate(params, seeds, coeffs, lr, mask,
                                   shard="clients")
-    sh_p = AG.seed_replay_aggregate(params, keys,
-                                    coeffs.at[2].set(1e6), lr, zo, mask,
+    sh_p = AG.seed_replay_aggregate(params, seeds,
+                                    coeffs.at[2].set(1e6), lr, mask,
                                     shard="clients")
     leaves_close(sh, sh_p, tol=0)
-
-    # kernel hash stream: same engine, bit-identical directions
-    seeds = O.fold_seed(jnp.int32(3), jnp.arange(n))
-    kf = AG.seed_replay_aggregate_kernel(params, seeds, coeffs, lr, mask)
-    ks = AG.seed_replay_aggregate_kernel(params, seeds, coeffs, lr, mask,
-                                         shard="clients")
-    leaves_close(kf, ks)
     print("SHARDED_OK devices=", jax.device_count())
 """
 
@@ -172,8 +136,8 @@ _SHARDED_PROG = """
 @pytest.mark.parametrize("devices", [1, 2, 4])
 def test_sharded_matches_flat_scan(devices):
     """shard='clients' over a 1/2/4-device host mesh reproduces the flat
-    scan (fp32 allclose), masked and unmasked, threefry and kernel-hash
-    paths, with and without chunking."""
+    scan (fp32 allclose), masked and unmasked, with and without
+    chunking."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}")
@@ -201,28 +165,6 @@ def _cnn_round_setup():
     state = {"client": params["client"], "server": params["server"],
              "opt_server": sopt.init(params["server"])}
     return api, state, sopt, round_batches, ds, make_optimizer
-
-
-def test_fed_round_seed_replay_matches_dense_at_h1():
-    api, state, sopt, round_batches, ds, make_optimizer = \
-        _cnn_round_setup()
-    lr = 2e-2
-    zo = Z.ZOConfig(mu=1e-3, n_pairs=2)
-    fed = P.FedConfig(n_clients=3, h=1)
-    rb = round_batches(ds, jax.random.PRNGKey(3), 3, 1, 16)
-    copt = make_optimizer("zo_sgd", lr)
-    dense = jax.jit(P.make_fed_round(api, "heron", zo, fed, copt, sopt))
-    lean = jax.jit(P.make_fed_round(api, "heron", zo, fed, copt, sopt,
-                                    uplink="seed_replay", client_lr=lr))
-    sd, md = dense(state, rb, jax.random.PRNGKey(9))
-    sl, ml = lean(state, rb, jax.random.PRNGKey(9))
-    for a, b in zip(jax.tree.leaves(sd["client"]),
-                    jax.tree.leaves(sl["client"])):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-5, atol=1e-6)
-    # the O(d) -> O(h*n_pairs) reduction is visible in the metrics
-    assert float(ml["uplink_bytes"]) < float(ml["uplink_bytes_dense"])
-    assert float(md["uplink_bytes"]) == float(md["uplink_bytes_dense"])
 
 
 def test_fed_round_seed_replay_validation():

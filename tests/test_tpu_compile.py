@@ -258,3 +258,44 @@ def test_server_attention_gradient_takes_flash_kernels(one_chip,
               if d.endswith(f",{c},{c}")
               and math.prod(map(int, d.split(","))) >= batch * 16 * c * c]
     assert scores == []
+
+
+def test_mesh_heron_step_keeps_mosaic_out(topo, one_chip, monkeypatch):
+    """The datacenter HERON step on a ("data", "model") mesh of all four
+    described chips (``chip_smoke.py --chips 4``, ``launch/train.py``)
+    compiles with the default forward_impl: GSPMD cannot partition a
+    Mosaic call, so on a multi-device mesh the dual probe runs the same
+    stream's jnp emulation and the server's attention stays on XLA."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.configs.gpt2 import gpt2_tiny
+    from repro.core import protocols as P
+    from repro.core import zo as Z
+    from repro.distributed.sharding import AxisRules, auto_mesh
+    from repro.kernels import ops as O
+    from repro.models import attention as A
+    from repro.models import transformer as T
+    from repro.optim.optimizers import make_optimizer
+
+    # the host is a CPU: steer the backend choice to the chip's
+    monkeypatch.setattr(O, "default_forward_impl", lambda: "pallas")
+    monkeypatch.setattr(O, "_interpret", lambda: False)
+    monkeypatch.setattr(A, "_fa_impl", lambda cfg: "pallas")
+    mesh = auto_mesh((2, 2), ("data", "model"), devices=topo.devices)
+    rules = AxisRules(mesh=mesh, enable_fsdp=False)
+    cfg = gpt2_tiny().replace(param_dtype="bfloat16",
+                              compute_dtype="bfloat16")
+    copt = make_optimizer("zo_sgd", 1e-4)
+    sopt = make_optimizer("adamw", 1e-4)
+    step = P.make_train_step(P.lm_api(cfg, rules), "heron",
+                             Z.ZOConfig(mu=1e-2), copt, sopt)
+    state = jax.eval_shape(
+        lambda p: P.init_train_state(jnp.zeros((2,), jnp.uint32), p, copt,
+                                     sopt), T.init_lm(None, cfg, mode="shape"))
+    rep = NamedSharding(mesh, PartitionSpec())
+    state = jax.tree.map(lambda s: _sds(rep, s.shape, s.dtype), state)
+    tok = _sds(rules.sharding_for((8, 2 * S), ("batch", None)), (8, 2 * S),
+               jnp.int32)
+    text = _hlo(step, state, {"inputs": tok, "labels": tok})
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text or "all-gather" in text

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import aggregate as AG
 from repro.core import protocols as P
 from repro.core import zo as Z
 from repro.distributed.sharding import AxisRules
@@ -96,20 +95,37 @@ def test_cnn_dual_halves_match_materialized_perturbation():
                                rtol=2e-5, atol=1e-5)
 
 
-def test_lm_dual_halves_match_materialized_perturbation():
-    cfg = _lm_cfg()
-    rules = AxisRules(mesh=None)
+# the dual forward of each block kind and position path: the kernel
+# lowering (GPT-2, in interpret mode), the generic fallback (recurrent
+# and xLSTM mixers, cross-attention, the capacity MoE) and M-RoPE's
+# (3, B, S) positions (the smoke configs, in the jnp emulation)
+DUAL_HALVES_ARCHS = ("gpt2-tiny", "recurrentgemma-9b", "xlstm-1.3b",
+                     "seamless-m4t-medium", "qwen3-moe-30b-a3b",
+                     "qwen2-vl-2b")
+
+
+@pytest.mark.parametrize("arch", DUAL_HALVES_ARCHS)
+def test_lm_dual_halves_match_materialized_perturbation(arch):
+    from repro.configs.registry import get_config
     from repro.models import transformer as T
+    from test_configs_smoke import smoke_batch
+    if arch == "gpt2-tiny":
+        cfg, impl = _lm_cfg(), "interpret"
+        batch = _lm_batch(cfg)
+    else:
+        cfg, impl = get_config(arch, smoke=True), "xla"
+        batch = smoke_batch(cfg)
+    rules = AxisRules(mesh=None)
     client = T.init_lm(jax.random.PRNGKey(0), cfg)["client"]
-    toks = _lm_batch(cfg)["inputs"]
+    toks, pos = batch["inputs"], batch.get("positions")
     mu = 0.01
     seeds = O.leaf_seed_tree(client, jnp.int32(13))
-    pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl="interpret")
-    s2, _ = T.client_forward(client, cfg, rules, toks, None, perturb=pz)
+    pz = O.Perturb(seeds=seeds, mu=mu, dual=True, impl=impl)
+    s2, _ = T.client_forward(client, cfg, rules, toks, pos, perturb=pz)
     B = toks.shape[0]
-    s_plain, _ = T.client_forward(client, cfg, rules, toks, None)
+    s_plain, _ = T.client_forward(client, cfg, rules, toks, pos)
     s_pert, _ = T.client_forward(O.perturb_tree(client, seeds, mu), cfg,
-                                 rules, toks, None)
+                                 rules, toks, pos)
     np.testing.assert_allclose(np.asarray(s2[:B]), np.asarray(s_plain),
                                rtol=2e-5, atol=1e-5)
     # scan-stacked layer leaves replay through per-rep row offsets —
@@ -169,7 +185,7 @@ def test_zo_gradient_kernel_coeff_contract():
 
     zo = Z.ZOConfig(mu=1e-3, n_pairs=3)
     base = jnp.int32(42)
-    g, info = Z.zo_gradient_kernel(dual_loss, params, base, zo)
+    g, info = Z.zo_gradient(dual_loss, params, base, zo)
     assert g["frozen"] is None
     assert info["coeffs"].shape == (3,)
     acc = jnp.zeros_like(params["w"])
@@ -187,54 +203,20 @@ def test_zo_gradient_kernel_coeff_contract():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_replay_gradient_kernel_roundtrip():
-    """(base_seed, coeffs) alone regenerate the estimator gradient —
-    the directions are bit-identical, the sum matches to FMA rounding."""
-    params = {"a": jax.random.normal(jax.random.PRNGKey(0), (4, 8)),
-              "b": {"c": jax.random.normal(jax.random.PRNGKey(1), (8,)),
-                    "froz": None}}
-
-    def dual_loss(p, seeds, mu):
-        pp = O.perturb_tree(p, seeds, mu)
-
-        def f(q):
-            return jnp.sum(q["a"] ** 2) + jnp.sum(jnp.sin(q["b"]["c"]))
-
-        return f(p), f(pp), None, {}
-
-    base = jnp.int32(9)
-    zo = Z.ZOConfig(mu=1e-3, n_pairs=2)
-    g, info = Z.zo_gradient_kernel(dual_loss, params, base, zo)
-    g2 = Z.replay_gradient_kernel(params, base, info["coeffs"])
-    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g2)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-    assert g2["b"]["froz"] is None
-
-
-def test_seed_replay_aggregate_kernel_matches_loop():
-    params = {"w": jax.random.normal(jax.random.PRNGKey(0), (6, 6))}
-    n, h, n_pairs, lr = 3, 2, 2, 0.05
-    coeffs = jax.random.normal(jax.random.PRNGKey(1), (n, h, n_pairs))
-    client_seeds = O.fold_seed(jnp.int32(77), jnp.arange(n))
-    out = AG.seed_replay_aggregate_kernel(params, client_seeds, coeffs,
-                                          lr)
-    acc = np.zeros((6, 6), np.float32)
-    for i in range(n):
-        for m in range(h):
-            for p in range(n_pairs):
-                seed = O.fold_seed(O.fold_seed(client_seeds[i],
-                                               jnp.int32(m)),
-                                   jnp.int32(p))
-                u = O.kernel_direction_tree(
-                    params, O.leaf_seed_tree(params, seed))["w"]
-                acc += np.asarray(-lr * float(coeffs[i, m, p]) * u / n)
-    np.testing.assert_allclose(np.asarray(out["w"]),
-                               np.asarray(params["w"]) + acc,
-                               rtol=2e-5, atol=1e-6)
-
-
 # --- protocol integration ----------------------------------------------------
+
+
+@pytest.mark.parametrize("api", ["lm", "cnn"])
+def test_forward_impl_names_the_two_backends(api):
+    """forward_impl is "kernel" (backend from the platform) or
+    "kernel_interpret"; any other value is refused by name."""
+    cfg = _lm_cfg("xla") if api == "lm" else _cnn_cfg("xla")
+    make = (lambda c: P.lm_api(c, AxisRules(mesh=None))) if api == "lm" \
+        else P.cnn_api
+    with pytest.raises(ValueError, match="'kernel' or 'kernel_interpret'"):
+        make(cfg)
+    assert P.forward_impl_of(_lm_cfg("kernel")) == O.default_forward_impl()
+    assert P.forward_impl_of(_cnn_cfg()) == "interpret"
 
 
 def test_kernel_train_step_smoke():
@@ -353,7 +335,6 @@ def _resnet18_client_shapes():
 
 
 def _gpt2_client_shapes():
-    cfg = _lm_cfg("xla")
     from repro.configs.gpt2 import gpt2_small
     full = gpt2_small()
     d, f = full.d_model, full.d_ff
