@@ -9,13 +9,18 @@ The single-stream kernel is a ``custom_vjp`` in FlashAttention-2 form:
 the forward (grid (BH / hb, nq, nk), kv innermost, ``hb`` heads a step)
 keeps each row's log-sum-exp; the backward recomputes the probabilities
 tile by tile from q, k and that statistic in a ``dq`` kernel (kv
-innermost) and a ``dk, dv`` kernel (q innermost).  Blocks the causal
-mask or the window hide are clamped out of the index maps and skipped.
+innermost) and a ``dk, dv`` kernel (q innermost).
 
 The dual kernel's grid is (B * H, nq, nk), the kv loop innermost; m/l/acc
 live in VMEM scratch and persist across kv steps (sequential TPU grid).
 Its kv-head BlockSpec index map folds the GQA group: q head h reads kv
 head h // (H // Kv).
+
+In both kernels a block that the causal mask or the window hides whole
+moves no data and does no work: the index maps clamp it onto the nearest
+live block, so that it shares that block's load, and ``pl.when`` skips
+its body.  At 8192 tokens on 512-token tiles that is 120 of a head's 256
+blocks (:func:`dual_live_blocks`).
 
 The dual kernel keeps TWO (m, l, acc) scratch sets and shares, per grid
 step, the K/V VMEM loads, the position iotas, and the mask between both
@@ -432,20 +437,20 @@ def _unflat(o, B, S):
 # ---------------------------------------------------------------------------
 
 def _zo_dual_fa_kernel(seed_ref, mu_ref, off_ref, qa_ref, qb_ref, k_ref,
-                       v_ref, *refs, nk: int, bq: int, bk: int,
-                       causal: bool, window: int, cap: float, scale: float,
-                       seq_kv: int, n_heads: int, seq_q: int,
+                       v_ref, *refs, p: _Plan, n_heads: int, seq_q: int,
                        shared_kv: bool, perturb_a: bool, perturb_b: bool):
     """Two online-softmax streams per grid step.
 
     Scratch layout is two full (m, l, acc) sets — the clean stream's set
-    updates with the exact op sequence of :func:`_fa_kernel`, so with
-    ``perturb_a=False`` its output bit-matches a separate
-    ``flash_attention`` call.  The position iotas and the mask are
-    computed once and shared; in ``shared_kv`` mode the K/V block loads
-    are shared too (the score-probe mode), otherwise the b-stream gets
-    its own K/V blocks (the weight-probe mode, where k/v diverged
-    upstream) and the fusion still halves the grid-step count.
+    updates with the op sequence of :func:`_fwd_kernel` on f32 operands,
+    so with ``perturb_a=False`` its output bit-matches a separate
+    ``flash_attention`` call over f32 inputs.  The position iotas and the
+    mask are computed once and shared; in ``shared_kv`` mode the K/V
+    block loads are shared too (the score-probe mode), otherwise the
+    b-stream gets its own K/V blocks (the weight-probe mode, where k/v
+    diverged upstream) and the fusion still halves the grid-step count.
+    A step the causal mask or the window hides whole does no work (its
+    K/V blocks are clamped onto the nearest live step's, so none moves).
     """
     if shared_kv:
         oa_ref, ob_ref, ma_ref, la_ref, acca_ref, mb_ref, lb_ref, \
@@ -467,54 +472,74 @@ def _zo_dual_fa_kernel(seed_ref, mu_ref, off_ref, qa_ref, qb_ref, k_ref,
         lb_ref[...] = jnp.zeros_like(lb_ref)
         accb_ref[...] = jnp.zeros_like(accb_ref)
 
-    # shared between both streams: positions, mask, (optionally) noise
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kv_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = kv_pos < seq_kv                             # padding
-    if causal:
-        mask &= q_pos >= kv_pos
-    if window > 0:
-        mask &= (q_pos - kv_pos) < window
-    noise = None
-    if perturb_a or perturb_b:
-        # canonical (n_heads*Sq, Skv) field; batch-independent, so the
-        # direction is one field per layer regardless of batch size
-        h = bh % n_heads
-        noise = uniform_noise(seed_ref[0, 0], (bq, bk),
-                              row_offset=off_ref[0, 0] + h * seq_q + qi * bq,
-                              col_offset=ki * bk)
+    @pl.when(_live(p, qi, ki))
+    def _step():
+        # shared between both streams: positions, mask, (optionally) noise
+        mask = _mask(p, qi, ki, transposed=False)
+        noise = None
+        if perturb_a or perturb_b:
+            # canonical (n_heads*Sq, Skv) field; batch-independent, so the
+            # direction is one field per layer regardless of batch size
+            h = bh % n_heads
+            noise = uniform_noise(
+                seed_ref[0, 0], (p.bq, p.bk),
+                row_offset=off_ref[0, 0] + h * seq_q + qi * p.bq,
+                col_offset=ki * p.bk)
 
-    def stream(q_ref2, kk_ref, vv_ref, m_ref, l_ref, acc_ref, o_ref,
-               pert: bool, mu_ix: int):
-        q = q_ref2[0].astype(jnp.float32)              # (bq, D)
-        k = kk_ref[0].astype(jnp.float32)              # (bk, D)
-        v = vv_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if cap > 0:
-            s = cap * jnp.tanh(s / cap)
-        if pert:
-            # post-softcap, pre-mask: an additive fixed-coordinate
-            # direction on the score field (masked positions never see it)
-            s = s + mu_ref[0, mu_ix] * noise
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        def stream(q_ref2, kk_ref, vv_ref, m_ref, l_ref, acc_ref,
+                   pert: bool, mu_ix: int):
+            q = q_ref2[0].astype(jnp.float32)          # (bq, D)
+            k = kk_ref[0].astype(jnp.float32)          # (bk, D)
+            v = vv_ref[0].astype(jnp.float32)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * p.scale
+            if p.cap > 0:
+                s = p.cap * jnp.tanh(s / p.cap)
+            if pert:
+                # post-softcap, pre-mask: an additive fixed-coordinate
+                # direction on the score field (masked positions never
+                # see it)
+                s = s + mu_ref[0, mu_ix] * noise
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            e = jnp.exp(s - m_new[:, None])
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(e, axis=1)
+            acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
+                e, v, preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
 
-        @pl.when(ki == nk - 1)
-        def _done():
+        stream(qa_ref, k_ref, v_ref, ma_ref, la_ref, acca_ref, perturb_a, 0)
+        stream(qb_ref, kb_ref, vb_ref, mb_ref, lb_ref, accb_ref, perturb_b,
+               1)
+
+    # the last kv step may be dead (causal: on every query block but the
+    # last), so the outputs are written whatever its liveness
+    @pl.when(ki == p.nk - 1)
+    def _done():
+        for l_ref, acc_ref, o_ref in ((la_ref, acca_ref, oa_ref),
+                                      (lb_ref, accb_ref, ob_ref)):
             l = jnp.maximum(l_ref[...], 1e-30)
             o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
-    stream(qa_ref, k_ref, v_ref, ma_ref, la_ref, acca_ref, oa_ref,
-           perturb_a, 0)
-    stream(qb_ref, kb_ref, vb_ref, mb_ref, lb_ref, accb_ref, ob_ref,
-           perturb_b, 1)
+
+def _dual_plan(Sq, Skv, bq, bk, causal, window, cap, scale, interpret):
+    """The :class:`_Plan` of one dual-kernel call: one head a step."""
+    (bq, Sq_p), (bk, Skv_p) = _seq_tiles(Sq, Skv, bq, bk, interpret)
+    return _Plan(1, bq, bk, Sq_p // bq, Skv_p // bk, Skv, bool(causal),
+                 int(window), float(cap), float(scale), interpret)
+
+
+def dual_live_blocks(Sq, Skv, bq=512, bk=512, causal=True, window=0):
+    """(live, total): the (q, kv) blocks of one head that the compiled
+    :func:`zo_dual_flash_attention` computes, of all its grid's, at the
+    tiles it picks for these shapes."""
+    p = _dual_plan(Sq, Skv, bq, bk, causal, window, 0.0, 1.0, False)
+    live = 0
+    for qi in range(p.nq):
+        lo, hi = _kv_range(p, qi)
+        live += int(hi) - int(lo) + 1
+    return live, p.nq * p.nk
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -542,8 +567,11 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
       own K/V (weight noise was applied upstream by ``zo_dual_matmul``);
       the fusion still halves the number of grid steps and shares the
       mask/position work, and each stream is bit-identical to a separate
-      ``flash_attention`` call over its own (q, k, v).
+      ``flash_attention`` call over its own (q, k, v) in f32.
 
+    As in :func:`flash_attention`, blocks that the causal mask or the
+    window hide move no data and do no work (:func:`dual_live_blocks`
+    counts them); the outputs are those of computing every block.
     Returns (oa, ob), each (B, Sq, H, Dv).
     """
     B, Sq, H, D = qa.shape
@@ -551,14 +579,15 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
     assert (kb is None) == (vb is None)
     Skv, Kv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // Kv
-    scale = float(scale) if scale is not None else D ** -0.5
-    (bq, Sq_p), (bk, Skv_p) = _seq_tiles(Sq, Skv, bq, bk, interpret)
-    nq, nk = Sq_p // bq, Skv_p // bk
+    p = _dual_plan(Sq, Skv, bq, bk, causal, window, cap,
+                   scale if scale is not None else D ** -0.5, interpret)
+    bq, bk = p.bq, p.bk
+    Sq_p, Skv_p = p.nq * bq, p.nk * bk
 
     def kv_index(bh, qi, ki):
         b = bh // H
         h = bh % H
-        return b * Kv + h // G, ki, 0
+        return b * Kv + h // G, _clamp(ki, *_kv_range(p, qi)), 0
 
     shared = kb is None
     seed_arr = jnp.asarray([[seed]], jnp.int32)
@@ -576,13 +605,11 @@ def zo_dual_flash_attention(qa, qb, k, v, kb=None, vb=None, seed=0,
         in_specs += [k_spec, v_spec]
         args += [_flat(kb, Skv_p), _flat(vb, Skv_p)]
     kernel = functools.partial(
-        _zo_dual_fa_kernel, nk=nk, bq=bq, bk=bk, causal=causal,
-        window=window, cap=float(cap), scale=scale, seq_kv=Skv,
-        n_heads=H, seq_q=Sq, shared_kv=shared, perturb_a=perturb_a,
-        perturb_b=perturb_b)
+        _zo_dual_fa_kernel, p=p, n_heads=H, seq_q=Sq, shared_kv=shared,
+        perturb_a=perturb_a, perturb_b=perturb_b)
     oa, ob = pl.pallas_call(
         kernel,
-        grid=(B * H, nq, nk),
+        grid=(B * H, p.nq, p.nk),
         in_specs=in_specs,
         out_specs=[o_spec, o_spec],
         out_shape=[jax.ShapeDtypeStruct((B * H, Sq_p, Dv), qa.dtype),

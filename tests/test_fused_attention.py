@@ -80,6 +80,111 @@ def test_clean_stream_bitmatches_single_flash(kw):
     np.testing.assert_array_equal(np.asarray(ob2), np.asarray(fb))
 
 
+# (Sq = Skv, bk) at bq 16, causal: whole (q, kv) blocks are masked.  53
+# tokens have no block in [8, 16] or [16, 32]: the last block is padded.
+# Compiled and interpret mode pick the same tiles for these shapes
+DEAD_SHAPES = [(64, 16), (64, 32), (53, 16), (53, 32)]
+
+
+@pytest.mark.parametrize("cap", [0.0, 5.0])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("probe", ["weights", "scores"])
+@pytest.mark.parametrize("window", [0, 8, 20])
+@pytest.mark.parametrize("seq,bk", DEAD_SHAPES)
+def test_dual_flash_with_dead_blocks(seq, bk, window, probe, dtype, cap):
+    """Shapes where the dual kernel skips blocks: both streams against
+    the oracle, and each unperturbed stream bitwise against the
+    single-stream kernel, which skips the same blocks (bf16 inputs:
+    against it over their f32 values, rounded to bf16 at the end)."""
+    live, total = FA.dual_live_blocks(seq, seq, 16, bk, True, window)
+    assert live < total
+    dt = jnp.dtype(dtype)
+    qa, qb, k, v, kb, vb = (t.astype(dt) for t in
+                            _qkv(B=1, Sq=seq, H=2, Kv=1, seed=3))
+    kw = dict(causal=True, window=window, cap=cap)
+    if probe == "weights":
+        oa, ob = FA.zo_dual_flash_attention(
+            qa, qb, k, v, kb=kb, vb=vb, perturb_b=False, bq=16, bk=bk,
+            interpret=True, **kw)
+        ra, rb = ref.zo_dual_flash_attention_ref(
+            qa, qb, k, v, kb=kb, vb=vb, perturb_b=False, **kw)
+        clean = [(oa, qa, k, v), (ob, qb, kb, vb)]
+    else:
+        oa, ob = FA.zo_dual_flash_attention(
+            qa, qb, k, v, seed=7, mu_b=0.1, perturb_b=True, bq=16, bk=bk,
+            interpret=True, **kw)
+        ra, rb = ref.zo_dual_flash_attention_ref(
+            qa, qb, k, v, u=O.attn_score_field(7, 2, seq, seq), mu_b=0.1,
+            perturb_b=True, **kw)
+        clean = [(oa, qa, k, v)]
+    tol = dict(rtol=1e-5, atol=1e-5) if dt == jnp.float32 else dict(
+        rtol=1e-2, atol=1e-3)
+    for got, want in ((oa, ra), (ob, rb)):
+        assert got.dtype == dt
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), **tol)
+    f32 = lambda t: t.astype(jnp.float32)
+    for got, q, kk, vv in clean:
+        single = FA.flash_attention(f32(q), f32(kk), f32(vv), bq=16, bk=bk,
+                                    interpret=True, **kw)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(single.astype(dt)))
+
+
+@pytest.mark.parametrize("probe", ["weights", "scores"])
+def test_dual_flash_reads_no_dead_block(probe):
+    """Keys past the last query are in blocks no query block sees.  NaN
+    there would reach the outputs through a masked block's product
+    (0 * NaN), so finite outputs equal to the oracle's over finite keys
+    show that no dead block's K/V reaches the kernel's arithmetic."""
+    Sq, Skv = 32, 64
+    qa, qb, k, v, kb, vb = _qkv(B=1, Sq=Skv, H=2, Kv=1, seed=4)
+    qa, qb = qa[:, :Sq], qb[:, :Sq]
+    dead = lambda t: t.at[:, Sq:].set(jnp.nan)
+    if probe == "weights":
+        kws = dict(kb=kb, vb=vb, perturb_b=False)
+        got = FA.zo_dual_flash_attention(
+            qa, qb, dead(k), dead(v), kb=dead(kb), vb=dead(vb),
+            perturb_b=False, bq=16, bk=16, interpret=True)
+    else:
+        kws = dict(u=O.attn_score_field(7, 2, Sq, Skv), mu_b=0.1)
+        got = FA.zo_dual_flash_attention(
+            qa, qb, dead(k), dead(v), seed=7, mu_b=0.1, bq=16, bk=16,
+            interpret=True)
+    want = ref.zo_dual_flash_attention_ref(qa, qb, k, v, **kws)
+    assert FA.dual_live_blocks(Sq, Skv, 16, 16) == (3, 8)
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 8),
+                                           (True, 20), (False, 0),
+                                           (False, 8)])
+@pytest.mark.parametrize("sq,skv,bq,bk", [
+    (64, 64, 16, 16), (64, 64, 16, 32), (53, 53, 16, 16),
+    (53, 53, 16, 32), (32, 64, 16, 16), (64, 32, 32, 16)])
+def test_dual_live_blocks_counts_unmasked_blocks(sq, skv, bq, bk, causal,
+                                                 window):
+    """The helper's count against a brute-force one: the blocks in
+    which the kernel's mask has a true entry."""
+    p = FA._dual_plan(sq, skv, bq, bk, causal, window, 0.0, 1.0, False)
+    brute = sum(bool(FA._mask(p, qi, ki, transposed=False).any())
+                for qi in range(p.nq) for ki in range(p.nk))
+    assert FA.dual_live_blocks(sq, skv, bq, bk, causal,
+                               window) == (brute, p.nq * p.nk)
+
+
+@pytest.mark.parametrize("seq,blocks", [(8192, (136, 256)),
+                                        (1024, (3, 4)), (128, (1, 1))])
+def test_dual_live_blocks_of_the_cells(seq, blocks):
+    """What the chip runs at the fed cells' lengths (Moonlight, GPT-2
+    Medium, GPT-2 Small) on 512-token tiles: a sequence within one tile
+    has no dead block."""
+    assert FA.dual_live_blocks(seq, seq) == blocks
+
+
 GRAD_CASES = {
     # 40 tokens on 16-row tiles: the last block of each axis is padded
     "mha_d64_causal": dict(H=2, Kv=2, D=64, Dv=64, kw=dict(causal=True)),

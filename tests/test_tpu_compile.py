@@ -145,7 +145,8 @@ def test_zo_dual_grouped_matmul_compiles(one_chip, k, n):
 
 def test_zo_dual_flash_attention_two_head_dims_compiles(one_chip):
     """MLA's weight probe: queries and keys of 192 dims, values of 128,
-    8192 tokens, vmapped over the cohort."""
+    8192 tokens, vmapped over the cohort.  The index maps that clamp the
+    masked kv blocks keep the cohort one call, not a loop over clients."""
     qk = _sds(one_chip, (ML_CLIENTS, 1, ML_SEQ, 16, 192))
     v = _sds(one_chip, (ML_CLIENTS, 1, ML_SEQ, 16, 128))
 
@@ -153,7 +154,12 @@ def test_zo_dual_flash_attention_two_head_dims_compiles(one_chip):
         return FA.zo_dual_flash_attention(qa, qb, k, v, kb, vb,
                                           perturb_b=False, interpret=False)
 
-    assert "tpu_custom_call" in _hlo(jax.vmap(one), qk, qk, qk, v, qk, v)
+    text = _hlo(jax.vmap(one), qk, qk, qk, v, qk, v)
+    calls = [op for _, rest, op in hlo_scopes.instructions(text)
+             if 'custom_call_target="tpu_custom_call"' in rest]
+    assert len(calls) == 1 and calls[0].endswith(
+        "vmap(jit(zo_dual_flash_attention))/pallas_call")
+    assert " while(" not in text
 
 
 def test_gpt2_medium_fed_round_compiles(one_chip, monkeypatch):
